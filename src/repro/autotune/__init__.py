@@ -1,6 +1,6 @@
 """Autotuned Pallas variant generation (see docs/autotune.md).
 
-Pipeline: each kernel package declares its tunable block/tile/unroll
+Pipeline: each kernel package declares its tunable block/tile
 axes in a ``space.py`` (:mod:`repro.autotune.space`); the tuner
 enumerates valid configurations (:mod:`.generate`), measures or
 analytically prices them per scenario bucket through the calibrate

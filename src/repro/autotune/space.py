@@ -1,7 +1,7 @@
 """Tunable parameter spaces: the shared vocabulary of the autotuner.
 
 Each of the six Pallas kernel packages declares its sweepable block/
-tile/unroll axes and a validity predicate in its own ``space.py`` (see
+tile axes and a validity predicate in its own ``space.py`` (see
 e.g. :mod:`repro.kernels.conv_im2col.space`) as a
 :class:`TunableSpace`.  Spaces come in two kinds:
 

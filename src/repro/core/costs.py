@@ -52,7 +52,7 @@ __all__ = ["CostModel", "ProfiledCostModel", "AnalyticCostModel",
            "fused_cost_key", "collective_cost_key", "ring_ag_bytes",
            "all_gather_time", "reduce_scatter_time", "all_reduce_time",
            "all_to_all_time", "send_time", "collective_time",
-           "COLLECTIVE_KINDS"]
+           "COLLECTIVE_KINDS", "device_spec", "TPU_SPECS"]
 
 #: bump when the *meaning* of costs changes (units, conventions, embedding)
 #: — persisted plan caches keyed on older schemas are invalidated.
@@ -449,6 +449,27 @@ TPU_V5E_SPEC = HardwareSpec(
                   "winograd": 8e-6, "fft": 1e-5, "pallas": 3e-6},
 )
 
+#: TPU specs by ``device_kind`` as JAX reports it
+TPU_SPECS = {"TPU v5 lite": TPU_V5E_SPEC, "TPU v5e": TPU_V5E_SPEC}
+
+
+def device_spec() -> HardwareSpec:
+    """The spec of this process's device.
+
+    ``CPU_SPEC`` on the CPU; a TPU's own spec by its ``device_kind``.
+    A kind with no spec raises: pricing one chip with another's rates
+    would pick plans for the wrong hardware.
+    """
+    d = jax.devices()[0]
+    if d.platform == "cpu":
+        return CPU_SPEC
+    spec = TPU_SPECS.get(d.device_kind) if d.platform == "tpu" else None
+    if spec is None:
+        raise ValueError(f"no HardwareSpec for {d.platform} device kind "
+                         f"{d.device_kind!r}; known TPU kinds: "
+                         f"{sorted(TPU_SPECS)}")
+    return spec
+
 
 # ----------------------------------------------------------------------
 # collective pricing (shared by sharding selection, the placement axis
@@ -578,11 +599,19 @@ class AnalyticCostModel(CostModel):
     im2col Toeplitz traffic, ...).  Activation traffic scales with the
     minibatch N (= ``scn.n``); weight traffic and the per-invocation
     ``setup`` do not — the two asymmetries that make primitive selection
-    batch-dependent."""
+    batch-dependent.
 
-    def __init__(self, spec: HardwareSpec = CPU_SPEC,
-                 include_tpu_only: bool = False):
-        self.spec = spec
+    Unless given, ``spec`` is :func:`device_spec` of this process's
+    device, and the ``tpu-only`` primitives are priced exactly when that
+    device is a TPU."""
+
+    def __init__(self, spec: Optional[HardwareSpec] = None,
+                 include_tpu_only: Optional[bool] = None):
+        # defaults follow the device this process runs on: its spec, and
+        # the tpu-only (Pallas) primitives exactly when it is a TPU
+        self.spec = spec if spec is not None else device_spec()
+        if include_tpu_only is None:
+            include_tpu_only = jax.devices()[0].platform == "tpu"
         self.include_tpu_only = include_tpu_only
 
     def _version_fields(self) -> str:
@@ -696,11 +725,8 @@ class AnalyticCostModel(CostModel):
             waste = _tile_waste(scn.m, bm)
             align = _lane_eff(bm)
             steps = _tile_steps(scn.m, bm) * kk
-            if p.get("unroll", 1):
-                if kk >= 25:  # 5x5 fully unrolled: code-size pressure
-                    align *= 0.95
-            else:  # rolled tap loop: per-tap control flow
-                steps += 4 * kk
+            if kk >= 25:  # 5x5 fully unrolled: code-size pressure
+                align *= 0.95
         else:
             return 1.0, 1.0, 0.0
         return waste, align, PALLAS_GRID_STEP_S * steps * scn.n

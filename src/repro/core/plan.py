@@ -259,6 +259,17 @@ def compile_plan(sel: SelectionResult, raw_params: Dict[str, Dict],
         (lambda v: jax.lax.optimization_barrier(v))
 
     if mesh is not None:
+        # place the packed weights on the mesh once: replicated, with tp
+        # weight slabs split over 'model'.  Left uncommitted they would
+        # sit on one device and be shipped to the others on every call.
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        def placed(nid, v):
+            tp_slab = kinds[nid] == "tp" and net.nodes[nid].kind == "conv"
+            return jax.device_put(
+                v, NamedSharding(mesh, P("model") if tp_slab else P()))
+        packed = {nid: placed(nid, v) for nid, v in packed.items()}
         if pp_nodes:
             fn = _build_pipeline_fn(sel, net, makers, mesh, batch, jit)
             mode = "pipeline"
@@ -356,11 +367,11 @@ def _build_mesh_fn(sel: SelectionResult, net: Net, makers: Dict[str,
 
     dp_spec = P(batch_axes) if batch_axes else P()
     if dp_nodes == len(net.order) and d_mesh > 1:
-        from jax.experimental.shard_map import shard_map
         inner = jax.vmap(_image_walker(sel, net, makers),
                          in_axes=(0, None))
-        fn = shard_map(inner, mesh=mesh, in_specs=(dp_spec, P()),
-                       out_specs=dp_spec)
+        # no varying-axes check: pallas_call outputs carry no vma type
+        fn = jax.shard_map(inner, mesh=mesh, in_specs=(dp_spec, P()),
+                           out_specs=dp_spec, check_vma=False)
         return (jax.jit(fn) if jit else fn), "shard_map"
 
     def spec_of(nid: str) -> "NamedSharding":
@@ -426,7 +437,6 @@ def _build_tp_fn(sel: SelectionResult, net: Net,
     axis across ``model``, and converts back — the intra-group
     collective the node's setup cost carried.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh_shape = mesh_shape_dict(mesh)
@@ -531,11 +541,11 @@ def _build_tp_fn(sel: SelectionResult, net: Net,
     p_specs = {nid: (P("model") if (net.nodes[nid].kind == "conv"
                                     and kind_of[nid] == "tp") else P())
                for nid in packed}
-    fn = shard_map(
+    fn = jax.shard_map(
         walker, mesh=mesh,
         in_specs=(spec(x_form), p_specs),
         out_specs={nid: spec(form_of[nid]) for nid in net.outputs()},
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn) if jit else fn
 
 

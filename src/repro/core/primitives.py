@@ -862,11 +862,8 @@ def build_registry() -> Tuple[Primitive, ...]:
         fused=_fft2d_fused(False, True))
 
     # ---------------- pallas (TPU kernels; analytic costs) ----------------
-    try:
-        from ..kernels import register_pallas_primitives
-        register_pallas_primitives(add, _sup)
-    except ImportError:  # pragma: no cover
-        pass
+    from ..kernels import register_pallas_primitives
+    register_pallas_primitives(add, _sup)
 
     names = [p.name for p in prims]
     assert len(names) == len(set(names)), "duplicate primitive names"

@@ -23,15 +23,8 @@ import numpy as np
 
 
 def register_pallas_primitives(add, _sup) -> None:
-    from ..core.scenario import Scenario
     from . import conv_direct, conv_im2col, winograd_gemm
     from .matmul import ops as mm_ops
-
-    def vmem_ok(scn: Scenario) -> bool:
-        # the direct kernel keeps the padded input strip in VMEM
-        hp = scn.h + 2 * scn.pad
-        wp = scn.w + 2 * scn.pad
-        return hp * wp * scn.c * 4 <= 8 * 2 ** 20
 
     # ---- direct NHWC ----
     def direct_prepare(scn, w, b):
@@ -40,8 +33,8 @@ def register_pallas_primitives(add, _sup) -> None:
 
     def direct_make(scn):
         def f(x, packed):  # x: HWC
-            return conv_direct.conv_direct(
-                x, packed["w"], packed["b"], stride=scn.stride, pad=scn.pad)
+            return conv_direct.conv_direct(x, packed["w"], packed["b"],
+                                           pad=scn.pad)
         return f
 
     def direct_fused(scn, l_in, l_out):
@@ -50,13 +43,15 @@ def register_pallas_primitives(add, _sup) -> None:
         # BlockSpec (see kernels/conv_direct/kernel.py)
         def f(x, packed):
             return conv_direct.conv_direct(
-                x, packed["w"], packed["b"], stride=scn.stride,
-                pad=scn.pad, in_layout=l_in, out_layout=l_out)
+                x, packed["w"], packed["b"], pad=scn.pad, in_layout=l_in,
+                out_layout=l_out)
         return f
 
-    base = _sup()
+    # stride 1 and VMEM-resident blocks: where the kernel compiles
+    direct_sup = _sup(stride1=True)
     add("pallas_direct_hwc", "pallas", "HWC", "HWC",
-        lambda s: base(s) and vmem_ok(s), direct_prepare, direct_make,
+        lambda s: direct_sup(s) and conv_direct.fits_vmem(s),
+        direct_prepare, direct_make,
         tags=("tpu-only",), fusable_in=("CHW",), fusable_out=("CHW",),
         fused=direct_fused)
 
@@ -79,7 +74,7 @@ def register_pallas_primitives(add, _sup) -> None:
                 pad=scn.pad, in_layout=l_in, out_layout=l_out)
         return f
 
-    add("pallas_im2col_chw", "pallas", "CHW", "CHW", base,
+    add("pallas_im2col_chw", "pallas", "CHW", "CHW", _sup(),
         im2_prepare, im2_make, tags=("tpu-only",),
         fusable_in=("HWC",), fusable_out=("HWC",), fused=im2_fused)
 

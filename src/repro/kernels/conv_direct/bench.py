@@ -4,6 +4,7 @@ from __future__ import annotations
 import numpy as np
 
 from ...core.scenario import Scenario
+from .kernel import fits_vmem
 
 
 def benchmark_entry(scn: Scenario):
@@ -12,7 +13,8 @@ def benchmark_entry(scn: Scenario):
     The builder defers tensor allocation and jit to measurement time so
     sweep planning (and ``--dry-run``) stays free.
     """
-    if scn.h + 2 * scn.pad < scn.k or scn.w + 2 * scn.pad < scn.k:
+    if (scn.stride != 1 or scn.h + 2 * scn.pad < scn.k
+            or scn.w + 2 * scn.pad < scn.k or not fits_vmem(scn)):
         return None
 
     def build():
@@ -24,8 +26,7 @@ def benchmark_entry(scn: Scenario):
         w = jnp.asarray(rng.normal(size=(scn.k, scn.k, scn.c, scn.m)) * 0.1,
                         jnp.float32)
         b = jnp.asarray(rng.normal(size=(scn.m,)), jnp.float32)
-        fn = lambda x, w, b: conv_direct(x, w, b, stride=scn.stride,
-                                         pad=scn.pad)
+        fn = lambda x, w, b: conv_direct(x, w, b, pad=scn.pad)
         return fn, (x, w, b)
 
     return build

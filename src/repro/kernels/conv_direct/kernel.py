@@ -5,12 +5,17 @@ loop nest (CPU) the kernel keeps the input strip in VMEM and performs
 one MXU matmul per kernel tap: for each (i, j) in K x K the shifted
 (OH*OW, C) window is multiplied with the (C, bm) weight slice and
 accumulated in an f32 VMEM scratch.  Grid is over output-channel tiles
-(bm, MXU-lane aligned); the spatial extent of one image layer fits VMEM
-for DNN-typical layer sizes (checked by the registry's supports()).
+(bm, MXU-lane aligned).  The whole strip must fit the kernel's scoped
+VMEM: :func:`fits_vmem` is the check the registry's ``supports()`` runs.
+
+Stride 1 only: Mosaic cannot lower a strided slice of a VMEM value
+(``vector.extract_strided_slice`` takes unit strides), so strided layers
+go to the other families.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -20,45 +25,69 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import use_interpret
 
 
-def _conv_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, k: int, stride: int,
-                 oh: int, ow: int, c: int, chw_in: bool, chw_out: bool,
-                 unroll: bool = True):
+#: scoped VMEM Mosaic grants one kernel on a TPU v5e unless the call
+#: asks for more (the chip holds 128 MiB)
+VMEM_LIMIT_BYTES = 16 * 2 ** 20
+
+
+def _tile_bytes(shape, itemsize: int = 4) -> int:
+    """VMEM bytes of an f32 buffer: its two minor dims pad to (8, 128)."""
+    *lead, sub, lane = shape
+    return (math.prod(lead) * (-(-sub // 8) * 8) * (-(-lane // 128) * 128)
+            * itemsize)
+
+
+def vmem_bytes(hp: int, wp: int, c: int, k: int, bm: int,
+               out_layout: str = "HWC") -> int:
+    """VMEM one grid step of :func:`conv_direct_pallas` holds, counted
+    with the HWC strip.
+
+    Counts the double-buffered blocks (input strip, (K, K, C, bm) weights,
+    bias, output tile), the f32 accumulator scratch, and the body's
+    values: the loaded strip, one tap window, one tap product and a
+    transposed output.  Against the compiler's own count this runs ~6%
+    high (VGG conv3: 22.3 MiB estimated, 21.0 MiB reported).  A strip
+    that arrives CHW through the fused prologue compiled wherever the
+    HWC strip fits, on every stride-1 layer of AlexNet, VGG-A and
+    GoogLeNet.
+    """
+    ohow = (hp - k + 1) * (wp - k + 1)
+    strip = _tile_bytes((hp, wp, c))
+    acc = _tile_bytes((ohow, bm))
+    o_blk = _tile_bytes((bm, ohow)) if out_layout == "CHW" else acc
+    blocks = 2 * (strip + _tile_bytes((k, k, c, bm)) + _tile_bytes((1, bm))
+                  + o_blk)
+    values = (strip + _tile_bytes((ohow, c)) + acc
+              + (o_blk if out_layout == "CHW" else 0))
+    return blocks + acc + values
+
+
+def fits_vmem(scn, bm: int = 128) -> bool:
+    """Whether the kernel fits the scoped VMEM at this scenario, in either
+    output layout the registry may fuse onto it."""
+    bm = min(bm, max(8, scn.m))  # the clamp conv_direct applies
+    hp, wp = scn.h + 2 * scn.pad, scn.w + 2 * scn.pad
+    return max(vmem_bytes(hp, wp, scn.c, scn.k, bm, lo)
+               for lo in ("HWC", "CHW")) <= VMEM_LIMIT_BYTES
+
+
+def _conv_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, k: int,
+                 oh: int, ow: int, c: int, chw_in: bool, chw_out: bool):
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    span_h = (oh - 1) * stride + 1
-    span_w = (ow - 1) * stride + 1
     xa = x_ref[...]  # whole strip lives in VMEM
     if chw_in:
         # fused prologue: the producer handed us CHW; remap to the
         # kernel's HWC working order while the strip is VMEM-resident
         # (no HBM transpose round trip)
         xa = jnp.transpose(xa, (1, 2, 0))
-    if unroll:
-        # fully unrolled K x K tap loop: one static MXU dot per tap
-        for i in range(k):
-            for j in range(k):
-                win = jax.lax.slice(
-                    xa, (i, j, 0), (i + span_h, j + span_w, c),
-                    (stride, stride, 1))
-                acc_ref[...] += jnp.dot(
-                    win.reshape(oh * ow, c), w_ref[i, j],
-                    preferred_element_type=jnp.float32)
-    else:
-        # rolled tap loop (autotune variant): one fori_loop iteration
-        # per tap — smaller program at the price of per-tap control flow
-        wa = w_ref[...]  # (K, K, C, bm)
-        bm = wa.shape[3]
-
-        def tap(t, _):
-            i, j = t // k, t % k
-            win = jax.lax.dynamic_slice(
-                xa, (i, j, 0), (span_h, span_w, c))[::stride, ::stride]
-            wt = jax.lax.dynamic_slice(
-                wa, (i, j, 0, 0), (1, 1, c, bm)).reshape(c, bm)
-            acc_ref[...] += jnp.dot(win.reshape(oh * ow, c), wt,
-                                    preferred_element_type=jnp.float32)
-            return 0
-
-        jax.lax.fori_loop(0, k * k, tap, 0)
+    # fully unrolled K x K tap loop: one static MXU dot per tap (Mosaic
+    # lowers no dynamic_slice of a VMEM value, so taps stay static)
+    for i in range(k):
+        for j in range(k):
+            win = jax.lax.slice(xa, (i, j, 0), (i + oh, j + ow, c))
+            acc_ref[...] += jnp.dot(
+                win.reshape(oh * ow, c), w_ref[i, j],
+                preferred_element_type=jnp.float32)
     out = acc_ref[...] + b_ref[...].astype(jnp.float32)
     if chw_out:
         # fused epilogue: emit the consumer's CHW layout through the
@@ -67,10 +96,10 @@ def _conv_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, k: int, stride: int,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def conv_direct_pallas(x, w, b, *, stride: int = 1, bm: int = 128,
+def conv_direct_pallas(x, w, b, *, bm: int = 128,
                        in_layout: str = "HWC", out_layout: str = "HWC",
-                       unroll: bool = True, interpret=None):
-    """Pre-padded single-image direct conv; w: (K, K, C, M), M % bm == 0.
+                       interpret=None):
+    """Pre-padded single-image stride-1 conv; w: (K, K, C, M), M % bm == 0.
 
     Layout-parameterized entry point: ``in_layout`` is the layout the
     input strip arrives in — ``"HWC"`` (native, shape (Hp, Wp, C)) or
@@ -88,14 +117,12 @@ def conv_direct_pallas(x, w, b, *, stride: int = 1, bm: int = 128,
         hp, wp, c = x.shape
     k, _, _, m = w.shape
     assert m % bm == 0
-    oh = (hp - k) // stride + 1
-    ow = (wp - k) // stride + 1
+    oh, ow = hp - k + 1, wp - k + 1
     if interpret is None:
         interpret = use_interpret()
 
-    kern = functools.partial(_conv_kernel, k=k, stride=stride, oh=oh,
-                             ow=ow, c=c, chw_in=chw_in, chw_out=chw_out,
-                             unroll=unroll)
+    kern = functools.partial(_conv_kernel, k=k, oh=oh,
+                             ow=ow, c=c, chw_in=chw_in, chw_out=chw_out)
     in_spec = pl.BlockSpec((c, hp, wp), lambda mi: (0, 0, 0)) if chw_in \
         else pl.BlockSpec((hp, wp, c), lambda mi: (0, 0, 0))
     out_spec = pl.BlockSpec((bm, oh * ow), lambda mi: (mi, 0)) if chw_out \

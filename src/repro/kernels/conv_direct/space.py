@@ -1,11 +1,9 @@
 """Tunable space of the direct NHWC kernel (autotune hook).
 
-Axes: ``bm`` — output-channel tile (the grid dimension); ``unroll`` —
-fully unrolled K x K tap loop (1) vs the rolled ``fori_loop`` variant
-(0), which trades per-tap control flow for a smaller kernel program.
-The input strip must fit VMEM, which depends on the scenario — that
-check lives in the generated primitive's ``supports``, same as the
-hand-written entry.
+Axis: ``bm`` — output-channel tile (the grid dimension).  The kernel
+runs stride 1 only, ``bm`` must tile the output channels in whole
+128-lane blocks (or span them), and the blocks must fit VMEM at that
+``bm``: the generated primitive's ``supports`` checks all three.
 """
 from __future__ import annotations
 
@@ -16,28 +14,24 @@ import numpy as np
 
 from ...autotune.space import TunableSpace, params_tuple
 from ...core.primitives import Primitive, _sup
+from ..common import lane_block_ok
+from .kernel import fits_vmem
 from .ops import conv_direct
 
 BASE_NAME = "pallas_direct_hwc"
 
-AXES = (("bm", (32, 64, 128, 256)),
-        ("unroll", (0, 1)))
+AXES = (("bm", (32, 64, 128, 256)),)
 
 
 def _valid(p) -> bool:
     return p["bm"] % 8 == 0
 
 
-def _vmem_ok(scn) -> bool:
-    # the kernel keeps the padded input strip in VMEM (see
-    # kernels/__init__.py::register_pallas_primitives)
-    hp = scn.h + 2 * scn.pad
-    wp = scn.w + 2 * scn.pad
-    return hp * wp * scn.c * 4 <= 8 * 2 ** 20
-
-
-def _supports(scn) -> bool:
-    return _sup()(scn) and _vmem_ok(scn)
+def _supports(bm):
+    # bm is the lane axis of the weight and output blocks
+    base = _sup(stride1=True)
+    return lambda scn: (base(scn) and lane_block_ok(bm, scn.m)
+                        and fits_vmem(scn, bm))
 
 
 def _prepare(scn, w, b):
@@ -45,34 +39,31 @@ def _prepare(scn, w, b):
             "b": jnp.asarray(b)}
 
 
-def _make(scn, *, bm, unroll):
+def _make(scn, *, bm):
     def f(x, packed):  # x: HWC
-        return conv_direct(x, packed["w"], packed["b"], stride=scn.stride,
-                           pad=scn.pad, bm=bm, unroll=bool(unroll))
+        return conv_direct(x, packed["w"], packed["b"], pad=scn.pad, bm=bm)
     return f
 
 
-def _fused(bm, unroll):
+def _fused(bm):
     def build(scn, l_in, l_out):
         def f(x, packed):
-            return conv_direct(x, packed["w"], packed["b"],
-                               stride=scn.stride, pad=scn.pad, bm=bm,
-                               unroll=bool(unroll),
-                               in_layout=l_in, out_layout=l_out)
+            return conv_direct(x, packed["w"], packed["b"], pad=scn.pad,
+                               bm=bm, in_layout=l_in, out_layout=l_out)
         return f
     return build
 
 
 def _make_primitive(params) -> Primitive:
-    bm, unroll = params["bm"], params["unroll"]
+    bm = params["bm"]
     return Primitive(
         name=SPACE.name_for(BASE_NAME, params),
         family="pallas", l_in="HWC", l_out="HWC",
-        supports=_supports, prepare=_prepare,
-        make=functools.partial(_make, bm=bm, unroll=unroll),
+        supports=_supports(bm), prepare=_prepare,
+        make=functools.partial(_make, bm=bm),
         tags=("tpu-only", "autotuned"),
         fusable_in=("CHW",), fusable_out=("CHW",),
-        fused=_fused(bm, unroll),
+        fused=_fused(bm),
         params=params_tuple(params, SPACE.axis_order))
 
 

@@ -1,12 +1,8 @@
 from .bench import benchmark_entry
-from .kernel import (
-    chw_to_hwc8_pallas, chw_to_hwc_pallas, hwc8_to_chw_pallas,
-    hwc_to_chw_pallas,
-)
-from .ops import chw_to_hwc, chw_to_hwc8, convert, hwc8_to_chw, hwc_to_chw
+from .kernel import chw_to_hwc_pallas, hwc_to_chw_pallas
+from .ops import chw_to_hwc, convert, hwc_to_chw
 from .ref import chw_to_hwc_ref, hwc_to_chw_ref
 
-__all__ = ["benchmark_entry", "chw_to_hwc", "hwc_to_chw", "chw_to_hwc8",
-           "hwc8_to_chw", "convert", "chw_to_hwc_pallas", "hwc_to_chw_pallas",
-           "chw_to_hwc8_pallas", "hwc8_to_chw_pallas", "chw_to_hwc_ref",
+__all__ = ["benchmark_entry", "chw_to_hwc", "hwc_to_chw", "convert",
+           "chw_to_hwc_pallas", "hwc_to_chw_pallas", "chw_to_hwc_ref",
            "hwc_to_chw_ref"]

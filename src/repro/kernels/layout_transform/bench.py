@@ -7,19 +7,14 @@ from ...core.scenario import Scenario
 
 
 def benchmark_entry(scn: Scenario):
-    """Zero-arg builder timing the tiled transform on the scenario's
-    input tensor, via the :func:`~repro.kernels.layout_transform.ops.
-    convert` dispatcher — the one-shot CHW->HWC8 kernel when the
-    channel count allows blocking, the CHW->HWC transpose otherwise."""
+    """Zero-arg builder timing the tiled CHW->HWC transpose on the
+    scenario's input tensor."""
     def build():
-        import jax
         import jax.numpy as jnp
 
-        from .ops import convert
+        from .ops import chw_to_hwc
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=scn.in_shape_chw), jnp.float32)
-        dst = "HWC8" if scn.c % 8 == 0 else "HWC"
-        fn = jax.jit(lambda a: convert(a, "CHW", dst))
-        return fn, (x,)
+        return chw_to_hwc, (x,)
 
     return build

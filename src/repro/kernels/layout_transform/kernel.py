@@ -54,56 +54,3 @@ def hwc_to_chw_pallas(x, *, bh: int = 8, bw: int = 128, interpret=None):
         out_shape=jax.ShapeDtypeStruct((c, h, w), x.dtype),
         interpret=interpret,
     )(x)
-
-
-# ----------------------------------------------------------------------
-# blocked-layout fusion: one-shot CHW <-> HWC8 tiles.  The DT graph only
-# reaches HWC8 through HWC (two materialized passes); these kernels fold
-# the permute and the channel blocking into a single grid so HBM sees
-# one read and one write.
-# ----------------------------------------------------------------------
-def _chw_to_hwc8_kernel(x_ref, o_ref):
-    x = x_ref[...]
-    c, bh, bw = x.shape
-    o_ref[...] = jnp.transpose(x, (1, 2, 0)).reshape(bh, bw, c // 8, 8)
-
-
-def _hwc8_to_chw_kernel(x_ref, o_ref):
-    x = x_ref[...]
-    bh, bw, cb, blk = x.shape
-    o_ref[...] = jnp.transpose(x.reshape(bh, bw, cb * blk), (2, 0, 1))
-
-
-def chw_to_hwc8_pallas(x, *, bh: int = 8, bw: int = 128, interpret=None):
-    """x: (C, H, W) -> (H, W, C/8, 8); C % 8 == H % bh == W % bw == 0."""
-    c, h, w = x.shape
-    assert c % 8 == 0 and h % bh == 0 and w % bw == 0
-    if interpret is None:
-        interpret = use_interpret()
-    return pl.pallas_call(
-        _chw_to_hwc8_kernel,
-        grid=(h // bh, w // bw),
-        in_specs=[pl.BlockSpec((c, bh, bw), lambda i, j: (0, i, j))],
-        out_specs=pl.BlockSpec((bh, bw, c // 8, 8),
-                               lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((h, w, c // 8, 8), x.dtype),
-        interpret=interpret,
-    )(x)
-
-
-def hwc8_to_chw_pallas(x, *, bh: int = 8, bw: int = 128, interpret=None):
-    """x: (H, W, C/8, 8) -> (C, H, W); H % bh == W % bw == 0."""
-    h, w, cb, blk = x.shape
-    assert blk == 8 and h % bh == 0 and w % bw == 0
-    c = cb * blk
-    if interpret is None:
-        interpret = use_interpret()
-    return pl.pallas_call(
-        _hwc8_to_chw_kernel,
-        grid=(h // bh, w // bw),
-        in_specs=[pl.BlockSpec((bh, bw, cb, blk),
-                               lambda i, j: (i, j, 0, 0))],
-        out_specs=pl.BlockSpec((c, bh, bw), lambda i, j: (0, i, j)),
-        out_shape=jax.ShapeDtypeStruct((c, h, w), x.dtype),
-        interpret=interpret,
-    )(x)

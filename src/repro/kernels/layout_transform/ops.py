@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import pad_to
-from .kernel import (
-    chw_to_hwc8_pallas, chw_to_hwc_pallas, hwc8_to_chw_pallas,
-    hwc_to_chw_pallas,
-)
+from .kernel import chw_to_hwc_pallas, hwc_to_chw_pallas
 
 
 @jax.jit
@@ -31,39 +28,10 @@ def hwc_to_chw(x):
     return hwc_to_chw_pallas(xp, bh=bh, bw=bw)[:, :h, :w]
 
 
-@jax.jit
-def chw_to_hwc8(x):
-    """One-shot (C, H, W) -> (H, W, C/8, 8); C % 8 == 0, any H/W.
-
-    Non-aligned spatial extents are zero-padded up to the tile grid and
-    cropped back after the kernel — the padding/cropping mirrors how
-    every other kernel wrapper legalizes odd shapes.
-    """
-    c, h, w = x.shape
-    bh = 8 if h >= 8 else h
-    bw = 128 if w >= 128 else w
-    xp, _ = pad_to(x, 1, bh)
-    xp, _ = pad_to(xp, 2, bw)
-    return chw_to_hwc8_pallas(xp, bh=bh, bw=bw)[:h, :w]
-
-
-@jax.jit
-def hwc8_to_chw(x):
-    """One-shot (H, W, C/8, 8) -> (C, H, W); any H/W (padded + cropped)."""
-    h, w, cb, blk = x.shape
-    bh = 8 if h >= 8 else h
-    bw = 128 if w >= 128 else w
-    xp, _ = pad_to(x, 0, bh)
-    xp, _ = pad_to(xp, 1, bw)
-    return hwc8_to_chw_pallas(xp, bh=bh, bw=bw)[:, :h, :w]
-
-
 #: direct tiled kernels by (src, dst) layout-name pair
 _DIRECT = {
     ("CHW", "HWC"): chw_to_hwc,
     ("HWC", "CHW"): hwc_to_chw,
-    ("CHW", "HWC8"): chw_to_hwc8,
-    ("HWC8", "CHW"): hwc8_to_chw,
 }
 
 

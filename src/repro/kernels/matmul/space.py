@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from ...autotune.space import TunableSpace, params_tuple
 from ...core.primitives import Primitive, _sup
+from ..common import lane_block_ok
 from .ops import matmul
 
 BASE_NAME = "pallas_pw_gemm_chw"
@@ -74,12 +75,25 @@ def _fused(bm, bn, bk):
     return build
 
 
+def _supports(bm, bn, bk):
+    # every block axis is a lane axis in some layout variant (the (K, M)
+    # prologue and (N, M) epilogue put M minor), so each must tile its
+    # GEMM dim in whole 128-lane blocks or span it
+    base = _sup(k_in=(1,))
+
+    def supports(scn) -> bool:
+        m, k = scn.m, scn.c
+        return (base(scn) and lane_block_ok(bm, m) and lane_block_ok(bk, k)
+                and lane_block_ok(bn, scn.out_h * scn.out_w))
+    return supports
+
+
 def _make_primitive(params) -> Primitive:
     bm, bn, bk = params["bm"], params["bn"], params["bk"]
     return Primitive(
         name=SPACE.name_for(BASE_NAME, params),
         family="pallas", l_in="CHW", l_out="CHW",
-        supports=_sup(k_in=(1,)), prepare=_prepare,
+        supports=_supports(bm, bn, bk), prepare=_prepare,
         make=functools.partial(_make, bm=bm, bn=bn, bk=bk),
         tags=("tpu-only", "autotuned"),
         fusable_in=("HWC",), fusable_out=("HWC",),

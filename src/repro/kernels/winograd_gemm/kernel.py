@@ -22,12 +22,12 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import use_interpret
 
 
-def _bgemm_kernel(u_ref, v_ref, o_ref, acc_ref):
+def _bgemm_kernel(u_ref, v_ref, o_ref, acc_ref, *, precision):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(u_ref[0], v_ref[0],
+    acc_ref[...] += jnp.dot(u_ref[0], v_ref[0], precision=precision,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
@@ -36,8 +36,12 @@ def _bgemm_kernel(u_ref, v_ref, o_ref, acc_ref):
 
 
 def winograd_bgemm_pallas(u, v, *, bn: int = 128, bc: int = 128,
-                          interpret=None):
-    """u: (P, M, C), v: (P, C, N) -> (P, M, N);  C % bc == N % bn == 0."""
+                          precision=None, interpret=None):
+    """u: (P, M, C), v: (P, C, N) -> (P, M, N);  C % bc == N % bn == 0.
+
+    ``precision`` is the MXU precision of the tile products (None: the
+    default, which rounds float32 operands to bfloat16 on a TPU).
+    """
     p, m, c = u.shape
     _, _, n = v.shape
     assert v.shape == (p, c, n) and n % bn == 0 and c % bc == 0
@@ -45,7 +49,7 @@ def winograd_bgemm_pallas(u, v, *, bn: int = 128, bc: int = 128,
         interpret = use_interpret()
 
     return pl.pallas_call(
-        _bgemm_kernel,
+        functools.partial(_bgemm_kernel, precision=precision),
         grid=(p, n // bn, c // bc),
         in_specs=[
             pl.BlockSpec((1, m, bc), lambda pp, j, kk: (pp, 0, kk)),

@@ -56,7 +56,13 @@ def conv_winograd(x, u, b, *, m_: int = 2, k: int = 3, stride: int = 1,
         xp[None], (a, a), (m_, m_), [(0, 0), (0, 0)],
         dimension_numbers=("NCHW", "OIHW", "NCHW"))[0]
     d = pt.reshape(c, a, a, nth * ntw)
-    V = jnp.einsum("ai,ciju,bj->abcu", Bt, d, Bt).reshape(a * a, c, -1)
+    # F(4, 3)'s transforms amplify rounding ~15x more than F(2, 3)'s
+    # (measured on a TPU v5e at GoogLeNet conv2 against a float32
+    # reference: 8.5e-2 vs 5.9e-3 of max|y| with bfloat16 MXU passes),
+    # so m >= 4 runs its transforms and tile products in float32
+    prec = lax.Precision.HIGHEST if m_ >= 4 else None
+    V = jnp.einsum("ai,ciju,bj->abcu", Bt, d, Bt,
+                   precision=prec).reshape(a * a, c, -1)
 
     n = nth * ntw
     bc_ = min(bc, max(8, c))
@@ -64,13 +70,14 @@ def conv_winograd(x, u, b, *, m_: int = 2, k: int = 3, stride: int = 1,
     Vp, _ = pad_to(V, 1, bc_)
     Vp, _ = pad_to(Vp, 2, bn_)
     Up, _ = pad_to(u, 2, bc_)
-    Q = winograd_bgemm_pallas(Up, Vp, bn=bn_, bc=bc_)[:, :, :n]
+    Q = winograd_bgemm_pallas(Up, Vp, bn=bn_, bc=bc_,
+                              precision=prec)[:, :, :n]
 
     Q = Q.reshape(a, a, m, nth, ntw)
     if out_layout == "HWC":
-        Y = jnp.einsum("ap,abmtu,bq->tpuqm", A, Q, A)
+        Y = jnp.einsum("ap,abmtu,bq->tpuqm", A, Q, A, precision=prec)
         y = Y.reshape(nth * m_, ntw * m_, m)[:oh, :ow, :]
         return y + b
-    Y = jnp.einsum("ap,abmtu,bq->mtpuq", A, Q, A)
+    Y = jnp.einsum("ap,abmtu,bq->mtpuq", A, Q, A, precision=prec)
     y = Y.reshape(m, nth * m_, ntw * m_)[:, :oh, :ow]
     return y + b[:, None, None]

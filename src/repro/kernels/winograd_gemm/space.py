@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from ...autotune.space import TunableSpace, params_tuple
 from ...core.primitives import Primitive, _sup
+from ..common import lane_block_ok
 from .ops import conv_winograd, prepare_kernel
 
 BASE_NAME = "pallas_wino_chw"
@@ -56,6 +57,18 @@ def _fused(m_, bn, bc):
     return build
 
 
+def _supports(m_, bn, bc):
+    # bc is the lane axis of the (M, bc) kernel-transform block, bn of
+    # the (bc, bn) input-transform and (M, bn) output blocks
+    base = _sup(k_in=(3,), stride1=True)
+
+    def supports(scn) -> bool:
+        tiles = -(-scn.out_h // m_) * -(-scn.out_w // m_)
+        return (base(scn) and lane_block_ok(bc, scn.c)
+                and lane_block_ok(bn, tiles))
+    return supports
+
+
 def _make_primitive(params) -> Primitive:
     m_, bn, bc = params["m_"], params["bn"], params["bc"]
     # keep the hand-written entries' name shape (pallas_wino_f{m}x3_…)
@@ -66,7 +79,7 @@ def _make_primitive(params) -> Primitive:
         name=SPACE.name_for(base, {k: v for k, v in params.items()
                                    if k != "m_"}),
         family="pallas", l_in="CHW", l_out="CHW",
-        supports=_sup(k_in=(3,), stride1=True),
+        supports=_supports(m_, bn, bc),
         prepare=_prepare(m_),
         make=functools.partial(_make, m_=m_, bn=bn, bc=bc),
         tags=("tpu-only", "autotuned"),

@@ -94,18 +94,11 @@ def force_host_devices(n: int) -> None:
 
 
 def make_mesh_compat(shape, axis_names):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist in newer
-    jax releases; on older ones every axis is implicitly Auto, which is
-    the only mode this repo uses — so fall back to the plain call.
-    """
-    try:
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with every axis Auto, the only mode this repo
+    uses (GSPMD and ``shard_map`` place the collectives)."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import pathlib
 import subprocess
 import sys
@@ -26,10 +27,13 @@ def _run_one(arch, shape, multi_pod, out_dir, timeout=3600):
            "--arch", arch, "--shape", shape, "--out", str(out_dir)]
     if multi_pod:
         cmd.append("--multi-pod")
+    # dry-runs compile for fake host devices only: pinned to the CPU,
+    # they never contend for an accelerator this parent may hold
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     t0 = time.time()
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=timeout)
+                              timeout=timeout, env=env)
         ok = proc.returncode == 0
         err = proc.stderr[-2000:] if not ok else ""
     except subprocess.TimeoutExpired:

@@ -295,4 +295,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

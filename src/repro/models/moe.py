@@ -139,7 +139,6 @@ def moe_ffn_alltoall(cfg, p, x, plan: ShardingPlan):
     # per (dest-shard, local-expert) capacity such that E*C splits evenly
     assert (e * c) % tp == 0
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -165,13 +164,13 @@ def moe_ffn_alltoall(cfg, p, x, plan: ShardingPlan):
         y = _local_combine(cfg, out_flat, meta, xf.shape[0], d, xl.dtype)
         return y.reshape(xl.shape)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axes or None, "model", None),
                   P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=P(data_axes or None, "model", None),
-        check_rep=False)
+        check_vma=False)
     return fn(x, p["router"], p["w1"], p["w3"], p["w2"])
 
 

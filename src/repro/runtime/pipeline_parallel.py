@@ -21,7 +21,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -80,9 +79,9 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stage_params,
             jnp.where(stage == s - 1, buf, jnp.zeros_like(buf)), axis)
         return buf
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x)

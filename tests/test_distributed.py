@@ -175,7 +175,6 @@ class TestCompression:
     def test_quantized_psum_close_to_exact(self):
         out = run_with_devices("""
             import jax, jax.numpy as jnp, numpy as np
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.optim import compressed_psum_tree
             from repro.launch.mesh import make_mesh_compat
@@ -184,8 +183,8 @@ class TestCompression:
             g = jnp.asarray(rng.normal(size=(8, 64, 32)), jnp.float32)
             def f(gl):
                 return compressed_psum_tree({'g': gl[0]}, 'pod')['g']
-            out = shard_map(f, mesh=mesh, in_specs=P('pod'),
-                            out_specs=P())(g)
+            out = jax.shard_map(f, mesh=mesh, in_specs=P('pod'),
+                                out_specs=P())(g)
             exact = jnp.mean(g, axis=0)
             rel = float(jnp.linalg.norm(out - exact) /
                         jnp.linalg.norm(exact))

@@ -8,6 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.primitives import registry
+from repro.core.scenario import Scenario
 from repro.kernels.conv_direct import conv_direct, conv_direct_ref
 from repro.kernels.conv_im2col import conv_im2col, conv_im2col_ref
 from repro.kernels.flash_attention import attention_ref, flash_attention
@@ -62,27 +64,44 @@ class TestMatmul:
 
 
 class TestConvDirect:
-    @pytest.mark.parametrize("h,w,c,m,k,stride,pad", [
-        (14, 14, 16, 32, 3, 1, 1),
-        (13, 9, 8, 16, 3, 2, 1),
-        (27, 27, 3, 16, 5, 2, 2),
-        (12, 12, 4, 8, 1, 1, 0),
-        (10, 10, 8, 130, 3, 1, 1),   # m > block
+    @pytest.mark.parametrize("h,w,c,m,k,pad", [
+        (14, 14, 16, 32, 3, 1),
+        (13, 9, 8, 16, 3, 1),
+        (27, 27, 3, 16, 5, 2),
+        (12, 12, 4, 8, 1, 0),
+        (10, 10, 8, 130, 3, 1),   # m > block
     ])
-    def test_shapes(self, h, w, c, m, k, stride, pad):
+    def test_shapes(self, h, w, c, m, k, pad):
         x = jnp.asarray(RNG.normal(size=(h, w, c)), jnp.float32)
         wt = jnp.asarray(RNG.normal(size=(k, k, c, m)) * 0.1, jnp.float32)
         b = jnp.asarray(RNG.normal(size=(m,)), jnp.float32)
-        got = conv_direct(x, wt, b, stride=stride, pad=pad)
-        want = conv_direct_ref(x, wt, b, stride=stride, pad=pad)
+        got = conv_direct(x, wt, b, pad=pad)
+        want = conv_direct_ref(x, wt, b, pad=pad)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("scn,offered", [
+        # GoogLeNet conv2 and a VGG conv4 layer fit the scoped VMEM
+        (Scenario(c=64, h=56, w=56, stride=1, k=3, m=192), True),
+        (Scenario(c=256, h=28, w=28, stride=1, k=3, m=512), True),
+        # VGG conv1 (C=3 pads to 128 lanes) and conv3 overflow it
+        (Scenario(c=3, h=224, w=224, stride=1, k=3, m=64), False),
+        (Scenario(c=256, h=56, w=56, stride=1, k=3, m=256), False),
+        # strided layers: GoogLeNet conv1, AlexNet conv1
+        (Scenario(c=3, h=224, w=224, stride=2, k=7, m=64), False),
+        (Scenario(c=3, h=227, w=227, stride=4, k=11, m=96, pad=0), False),
+    ])
+    def test_registry_offers_it_where_it_compiles(self, scn, offered):
+        """supports() of the direct kernel matches what the v5e compiler
+        accepts (tests/test_tpu_compile.py compiles the same layers)."""
+        prim = next(p for p in registry() if p.name == "pallas_direct_hwc")
+        assert prim.supports(scn) is offered
 
     def test_bf16(self):
         x = jnp.asarray(RNG.normal(size=(8, 8, 8)), jnp.bfloat16)
         wt = jnp.asarray(RNG.normal(size=(3, 3, 8, 16)) * 0.1, jnp.bfloat16)
         b = jnp.zeros((16,), jnp.bfloat16)
-        got = conv_direct(x, wt, b, stride=1, pad=1)
-        want = conv_direct_ref(x, wt, b, stride=1, pad=1)
+        got = conv_direct(x, wt, b, pad=1)
+        want = conv_direct_ref(x, wt, b, pad=1)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=5e-2, atol=5e-2)

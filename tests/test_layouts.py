@@ -2,10 +2,7 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal install: property tests skip, units run
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.layouts import (
     ALL_LAYOUTS, CHW, HWC, HCW, HWC8, DTGraph, default_dt_graph,
@@ -80,15 +77,15 @@ class TestConvertLayoutRoundTrip:
                 np.testing.assert_allclose(np.asarray(got), b.to_memory(x),
                                            rtol=0, atol=0)
 
-    def test_hwc8_pallas_pad_crop(self):
-        """The one-shot CHW<->HWC8 tiled kernels agree with the layout
-        reference at spatial extents that force padding + cropping."""
-        from repro.kernels.layout_transform import chw_to_hwc8, hwc8_to_chw
+    def test_chw_hwc_pallas_pad_crop(self):
+        """The tiled CHW<->HWC kernels agree with the layout reference at
+        spatial extents that force padding + cropping."""
+        from repro.kernels.layout_transform import chw_to_hwc, hwc_to_chw
         rng = np.random.default_rng(3)
         x = rng.normal(size=(16, 11, 13)).astype(np.float32)  # odd H/W
-        mem = np.asarray(chw_to_hwc8(x))
-        np.testing.assert_allclose(mem, HWC8.to_memory(x), rtol=0, atol=0)
-        np.testing.assert_allclose(np.asarray(hwc8_to_chw(mem)), x,
+        mem = np.asarray(chw_to_hwc(x))
+        np.testing.assert_allclose(mem, HWC.to_memory(x), rtol=0, atol=0)
+        np.testing.assert_allclose(np.asarray(hwc_to_chw(mem)), x,
                                    rtol=0, atol=0)
 
 

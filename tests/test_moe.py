@@ -4,10 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal install: property tests skip, units run
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.models.moe import capacity, moe_defs, moe_ffn

@@ -2,10 +2,7 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal install: property tests skip, units run
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import pbqp
 from repro.core.pbqp import PBQP, Infeasible, brute_force, solve

@@ -2,9 +2,7 @@
 
 Three invariant families, each stated once as a plain checker and driven
 two ways — by hypothesis (random structured instances, shrinking on
-failure) and by a seeded ``np.random`` smoke loop that runs even on
-minimal installs where hypothesis is absent, so the invariants are never
-completely untested:
+failure) and by a seeded ``np.random`` smoke loop over fixed instances:
 
 1. **Exactness** — the reduction + branch-and-bound solver agrees with
    exhaustive enumeration on every instance small enough to enumerate
@@ -21,10 +19,7 @@ completely untested:
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal install: property tests skip, units run
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.pbqp import PBQP, Infeasible, brute_force, solve, \
     solve_warm
@@ -63,7 +58,7 @@ def pbqp_instances(draw):
 
 def random_pbqp(rng: np.random.Generator) -> PBQP:
     """Same distribution as :func:`pbqp_instances`, seeded numpy draw —
-    the no-hypothesis smoke loop and failure reproduction both use it."""
+    the seeded smoke loop and failure reproduction both use it."""
     n = int(rng.integers(2, 7))
     doms = [int(rng.integers(1, 5)) for _ in range(n)]
     node_costs = [rng.uniform(0, 100, size=k) for k in doms]
@@ -221,9 +216,8 @@ class TestSelectionProperties:
 
 
 # ----------------------------------------------------------------------
-# seeded smoke loop: the same checkers, no hypothesis required.  Keeps
-# the invariants exercised on minimal installs (and makes any hypothesis
-# failure trivially reproducible from its numpy seed).
+# seeded smoke loop: the same checkers over fixed numpy draws (makes any
+# hypothesis failure trivially reproducible from its numpy seed).
 # ----------------------------------------------------------------------
 class TestSeededSmoke:
     def test_exact_and_warm_seeded(self):

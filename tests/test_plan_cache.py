@@ -194,6 +194,44 @@ class TestKeyInvalidation:
             plan_key(net.fingerprint(), "c4h32w32", v)
 
 
+class TestDeviceDefaults:
+    """The analytic model and the Pallas interpret switch follow the
+    device this process runs on, and refuse one they do not know."""
+
+    @staticmethod
+    def _on(monkeypatch, platform, kind):
+        import types
+
+        import jax
+        dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+        monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+
+    def test_cpu(self):
+        from repro.kernels.common import use_interpret
+        cm = AnalyticCostModel()
+        assert cm.spec is CPU_SPEC and not cm.include_tpu_only
+        assert use_interpret()
+
+    def test_v5e(self, monkeypatch):
+        from repro.core.costs import TPU_V5E_SPEC
+        from repro.kernels.common import use_interpret
+        self._on(monkeypatch, "tpu", "TPU v5 lite")
+        cm = AnalyticCostModel()
+        assert cm.spec is TPU_V5E_SPEC and cm.include_tpu_only
+        assert not use_interpret()
+
+    @pytest.mark.parametrize("platform,kind", [("tpu", "TPU v9"),
+                                               ("gpu", "H100")])
+    def test_unknown_device_raises(self, monkeypatch, platform, kind):
+        from repro.kernels.common import use_interpret
+        self._on(monkeypatch, platform, kind)
+        with pytest.raises(ValueError, match="no HardwareSpec"):
+            AnalyticCostModel()
+        if platform != "tpu":
+            with pytest.raises(RuntimeError, match="Pallas"):
+                use_interpret()
+
+
 class TestLRU:
     def test_hit_miss_eviction(self):
         lru = LRU(2)
