@@ -30,11 +30,13 @@ docs/distributed.md.
 """
 from __future__ import annotations
 
+import re
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
 
 import jax
+import jax.monitoring
 import jax.numpy as jnp
 import numpy as np
 
@@ -45,8 +47,8 @@ from .layouts import LAYOUT_BY_NAME
 from .primitives import convert_layout
 from .selection import Placement, SelectionResult, pp_microbatches
 
-__all__ = ["compile_plan", "CompiledNet", "measure", "compile_count",
-           "mesh_shape_dict"]
+__all__ = ["compile_plan", "CompiledNet", "measure", "xla_compile_stats",
+           "mesh_shape_dict", "node_scope", "edge_scope"]
 
 
 def mesh_shape_dict(mesh) -> Dict[str, int]:
@@ -54,16 +56,104 @@ def mesh_shape_dict(mesh) -> Dict[str, int]:
     ``launch.mesh`` re-exports it for CLI-side callers."""
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
-#: process-wide count of compile_plan() calls — executable construction is
-#: the expensive step the serving LRU exists to amortise, so tests and the
-#: plan-cache benchmark assert on this.  Backed by the obs registry's
-#: locked Counter: PlanServer.prefetch compiles from an executor, and the
-#: old ``global n; n += 1`` lost increments under that concurrency.
-_COMPILE_COUNTER = default_registry().counter("compile_plan_calls")
+#: JAX's event for an executable built, by compiling or by a load from
+#: the persistent compilation cache
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: process-wide: every XLA executable JAX builds, whoever asks for it —
+#: the cost the serving LRU and the plan cache exist to amortise
+_XLA_COMPILES = default_registry().counter("xla_compiles")
+_XLA_COMPILE_S = default_registry().counter("xla_compile_s")
 
 
-def compile_count() -> int:
-    return _COMPILE_COUNTER.value
+def _on_jax_event(event: str, duration: float, **kw) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        _XLA_COMPILES.add()
+        _XLA_COMPILE_S.add(float(duration))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+
+
+def xla_compile_stats() -> Dict[str, float]:
+    """Executables XLA has built in this process, and their seconds."""
+    return {"xla_compiles": _XLA_COMPILES.value,
+            "xla_compile_s": float(_XLA_COMPILE_S.value)}
+
+
+def node_scope(nid: str) -> str:
+    """``jax.named_scope`` of a PBQP node's primitive or op call."""
+    return f"node:{nid}"
+
+
+def edge_scope(src: str, dst: str) -> str:
+    """``jax.named_scope`` of the layout conversion chain on an edge;
+    ``dst`` is ``"out"`` for an output's conversion to logical CHW."""
+    return f"edge:{src}->{dst}"
+
+
+#: a scope in an HLO instruction's ``op_name`` metadata: between path
+#: separators, or inside a transform's parentheses (``vmap(node:c1)``)
+_SCOPE_RE = re.compile(r"(?:node|edge):[^/()\"]+")
+_INSTR_RE = re.compile(r"^\s+(?:ROOT\s+)?%([^\s=]+)\s*=(.*)$")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_FUSION_CALLS_RE = re.compile(r"\bfusion\(.*?\bcalls=%([^\s,}]+)")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+
+
+def hlo_op_scopes(hlo_text: str, scopes) -> Dict[str, str]:
+    """``{instruction name: scope}`` over an HLO module's text, for the
+    instructions a device runs (those outside fusion bodies).
+
+    An instruction's scope is the innermost of ``scopes`` in its
+    ``op_name`` metadata; a fusion without one takes the commonest scope
+    of its body.  An instruction XLA added with no scope at all — an
+    input or weight copy, a prefetch, a bitcast — takes the scope of
+    its first consumer that has one: it exists to feed that consumer.
+    """
+    comps: Dict[str, list] = {}
+    body: list = []
+    for line in hlo_text.splitlines():
+        if line.startswith("%") or line.startswith("ENTRY"):
+            name = line.split("%", 1)[1].split()[0]
+            body = comps.setdefault(name, [])
+            continue
+        m = _INSTR_RE.match(line)
+        if m is not None:
+            meta = _OP_NAME_RE.search(m.group(2))
+            own = [t for t in _SCOPE_RE.findall(meta.group(1))
+                   if t in scopes] if meta else []
+            body.append((m.group(1), m.group(2), own[-1] if own else None))
+    fused = {c for _, rhs, _ in (i for b in comps.values() for i in b)
+             for c in _FUSION_CALLS_RE.findall(rhs)}
+
+    def body_scope(comp: str) -> Optional[str]:
+        found = []
+        for _, rhs, own in comps.get(comp, ()):
+            inner = _FUSION_CALLS_RE.findall(rhs)
+            found.append(own or (body_scope(inner[0]) if inner else None))
+        found = [f for f in found if f]
+        return max(set(found), key=found.count) if found else None
+
+    out: Dict[str, str] = {}
+    for comp, instrs in comps.items():
+        if comp in fused:
+            continue
+        scope: Dict[str, Optional[str]] = {}
+        for name, rhs, own in instrs:
+            inner = _FUSION_CALLS_RE.findall(rhs)
+            scope[name] = own or (body_scope(inner[0]) if inner else None)
+        # walking back from the last instruction, every consumer of an
+        # unscoped one is settled before it is, and the earliest
+        # consumer is the last to write
+        unscoped = {n for n, s in scope.items() if s is None}
+        for name, rhs, _ in reversed(instrs):
+            if scope[name] is None:
+                continue
+            for op in _OPERAND_RE.findall(rhs):
+                if op in unscoped:
+                    scope[op] = scope[name]
+        out.update((n, s) for n, s in scope.items() if s is not None)
+    return out
 
 
 @dataclass
@@ -98,6 +188,28 @@ class CompiledNet:
 
     def __call__(self, x):
         return self.fn(jnp.asarray(x), self.params)
+
+    def scopes(self) -> set:
+        """Every ``node:``/``edge:`` scope the plan's executable opens."""
+        sel, net = self.sel, self.sel.net
+        out = {node_scope(nid) for nid in net.order
+               if net.nodes[nid].kind != "input"}
+        out |= {edge_scope(src, dst) for (src, dst), chain
+                in sel.conversions.items() if chain}
+        out |= {edge_scope(nid, "out") for nid in net.outputs()
+                if sel.choices[nid].l_out != "CHW"}
+        return out
+
+    def op_scopes(self, x_shape) -> Dict[str, str]:
+        """``{HLO instruction name: scope}`` of the executable compiled
+        for input shape ``x_shape``: which PBQP node (``node:<id>``) or
+        layout conversion edge (``edge:<src>-><dst>``) each instruction
+        of the optimized module — and so each device op of a profile —
+        belongs to.  Lowers and compiles, which for a shape already run
+        is a cache hit.  Needs a jitted executable (``jit=True``)."""
+        x = jax.ShapeDtypeStruct(tuple(x_shape), jnp.float32)
+        text = self.fn.lower(x, self.params).compile().as_text()
+        return hlo_op_scopes(text, self.scopes())
 
 
 def compile_plan(sel: SelectionResult, raw_params: Dict[str, Dict],
@@ -143,7 +255,6 @@ def compile_plan(sel: SelectionResult, raw_params: Dict[str, Dict],
     outputs, so a mesh executable is a drop-in for the single-device
     batched one (verified output-identical in tests/test_distributed.py).
     """
-    _COMPILE_COUNTER.add()
     if batch < 1:
         raise ValueError(f"batch must be >= 1, got {batch}")
     if mesh is not None and batch < 2:
@@ -324,17 +435,25 @@ def _image_walker(sel: SelectionResult, net: Net,
                 v = vals[src]
                 chain = sel.conversions.get((src, nid))
                 if chain:
-                    for a, b in zip(chain, chain[1:]):
-                        v = barrier(convert_layout(v, a, b))
+                    with jax.named_scope(edge_scope(src, nid)):
+                        for a, b in zip(chain, chain[1:]):
+                            v = barrier(convert_layout(v, a, b))
                 ins.append(v)
-            if node.kind == "conv":
-                vals[nid] = barrier(makers[nid](ins[0], params[nid]))
-            else:
-                layout = LAYOUT_BY_NAME[sel.choices[nid].l_in]
-                vals[nid] = node.op.fn(ins, layout, params.get(nid))
-        return {nid: convert_layout(vals[nid], sel.choices[nid].l_out,
-                                    "CHW")
-                for nid in net.outputs()}
+            with jax.named_scope(node_scope(nid)):
+                if node.kind == "conv":
+                    vals[nid] = barrier(makers[nid](ins[0], params[nid]))
+                else:
+                    layout = LAYOUT_BY_NAME[sel.choices[nid].l_in]
+                    vals[nid] = node.op.fn(ins, layout, params.get(nid))
+        outs = {}
+        for nid in net.outputs():
+            l_out = sel.choices[nid].l_out
+            if l_out == "CHW":
+                outs[nid] = vals[nid]
+                continue
+            with jax.named_scope(edge_scope(nid, "out")):
+                outs[nid] = convert_layout(vals[nid], l_out, "CHW")
+        return outs
     return run
 
 

@@ -10,10 +10,11 @@ Three pillars (docs/observability.md):
   plans, predicted-vs-observed EWMA drift scores, and targeted
   recalibration of the flagged calibration entries.
 
-``trace`` and ``metrics`` are stdlib-only so :mod:`repro.core` can
-import them.  ``drift`` imports back into core/serving, so it is
-loaded lazily here (module ``__getattr__``) — importing
-:mod:`repro.obs` from inside core never recurses.
+``trace`` and ``metrics`` import nothing of the program (``trace``
+imports :mod:`jax` lazily) so :mod:`repro.core` can import them.
+``drift`` imports back into core/serving, so it is loaded lazily here
+(module ``__getattr__``) — importing :mod:`repro.obs` from inside core
+never recurses.
 """
 from __future__ import annotations
 

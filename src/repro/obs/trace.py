@@ -1,23 +1,31 @@
-"""Request-scoped tracing: nested spans emitted as thread-safe JSONL.
+"""Request-scoped tracing: nested spans, written to two sinks.
 
 A *span* is one timed region of the serve path (``infer``,
-``plan``, ``pbqp.solve``, ``compile``, ``execute``, ``crop``,
-``queue_wait`` — docs/observability.md lists the schema).  Spans nest
+``plan``, ``pbqp.solve``, ``execute``, ``fetch``, ``crop``,
+``sched.batch``, ... — docs/observability.md lists them).  Spans nest
 through a :mod:`contextvars` variable, so the parent/child structure is
 correct across the thread pool the :class:`~repro.serving.server.
 PlanServer` resolves misses on: each worker thread carries its own
 current-span context.
 
-Tracing is OFF by default and the disabled path is a few attribute
-reads — the serve hot path stays uninstrumented-cost until someone
-calls :func:`configure` (the ``--trace`` flag of ``launch/serve.py``).
-Finished spans are written as one JSON line each (children appear
-before their parent, which closes last); the writer holds a lock, so
-concurrent requests interleave whole lines, never bytes.
+Sinks:
 
-This module is intentionally stdlib-only: :mod:`repro.core` imports it
-(``pbqp.solve`` / ``compile_plan`` open spans), so it must never import
-back into core.
+* **JSONL** — OFF by default; :func:`configure` (the ``--trace`` flag of
+  ``launch/serve.py``) turns it on.  Finished spans are written as one
+  JSON line each (children appear before their parent, which closes
+  last); the writer holds a lock, so concurrent requests interleave
+  whole lines, never bytes.
+* **the profiler** — whenever a ``jax.profiler`` capture is running
+  (``TraceAnnotation.is_enabled()``), every span also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name carrying its
+  attributes, so the program's spans sit on the device trace's clock.
+  Spans emitted from explicit timestamps (:meth:`Tracer.emit`) stay
+  JSONL-only; in the profile their duration becomes a ``<name>_s``
+  attribute of the enclosing span.
+
+With neither sink active a span is one flag read and one
+``is_enabled()`` call.  :mod:`jax` is imported lazily: :mod:`repro.core`
+imports this module (``pbqp.solve`` opens spans).
 """
 from __future__ import annotations
 
@@ -28,7 +36,8 @@ import json
 import pathlib
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional, Union
+from typing import (Any, ContextManager, Dict, Iterator, List, Optional,
+                    Union)
 
 __all__ = ["Span", "Tracer", "get_tracer", "configure", "NULL_SPAN"]
 
@@ -36,35 +45,64 @@ __all__ = ["Span", "Tracer", "get_tracer", "configure", "NULL_SPAN"]
 class Span:
     """One open region; ``set(**attrs)`` attaches attributes."""
 
-    __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "attrs")
+    __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "attrs",
+                 "annotation")
 
     def __init__(self, name: str, trace_id: int, span_id: int,
-                 parent_id: Optional[int], attrs: Dict[str, Any]):
+                 parent_id: Optional[int], attrs: Dict[str, Any],
+                 annotation=None):
         self.name = name
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
         self.t0 = time.perf_counter()
         self.attrs = attrs
+        #: the span's ``TraceAnnotation`` while a profile is capturing
+        self.annotation = annotation
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
+        if self.annotation is not None:
+            self.annotation.set_metadata(**attrs)
 
 
 class _NullSpan:
-    """What call sites get when tracing is disabled: ``set`` is a no-op."""
+    """What call sites get when no sink is active: ``set`` is a no-op."""
 
     __slots__ = ()
 
     def set(self, **attrs) -> None:
         pass
 
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
 
 NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, imported on first use
+_annotation_cls = None
+
+
+def _annotation_type():
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+def _profiling() -> bool:
+    """Whether a ``jax.profiler`` capture is running in this process."""
+    return _annotation_type().is_enabled()
+
 
 class Tracer:
-    """Span factory + JSONL sink.
+    """Span factory + JSONL sink; spans also reach the profiler while
+    it captures.
 
     ``sink`` is a path (opened append), a file-like object, or a
     ``list`` (records appended as dicts — the test/in-memory sink).
@@ -100,35 +138,50 @@ class Tracer:
             if self._fh is not None:
                 self._fh.write(json.dumps(rec) + "\n")
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs) -> Iterator[Union[Span, _NullSpan]]:
+    def span(self, name: str, **attrs
+             ) -> ContextManager[Union[Span, _NullSpan]]:
         """Open a span; a span with no live parent starts a new trace."""
-        if not self.enabled:
-            yield NULL_SPAN
-            return
+        if not self.enabled and not _profiling():
+            return NULL_SPAN
+        return self._span(name, attrs)
+
+    @contextlib.contextmanager
+    def _span(self, name: str, attrs: Dict[str, Any]) -> Iterator[Span]:
+        annotation = None
+        if _profiling():
+            annotation = _annotation_type()(name, **attrs)
+            annotation.__enter__()
         parent = self._current.get()
         sid = self._next_id()
         sp = Span(name, parent.trace_id if parent else sid, sid,
-                  parent.span_id if parent else None, dict(attrs))
+                  parent.span_id if parent else None, dict(attrs),
+                  annotation)
         token = self._current.set(sp)
         try:
             yield sp
         finally:
             self._current.reset(token)
-            self._emit({"name": sp.name, "trace": sp.trace_id,
-                        "span": sp.span_id, "parent": sp.parent_id,
-                        "t0": sp.t0,
-                        "dur_s": time.perf_counter() - sp.t0,
-                        **sp.attrs})
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            if self.enabled:
+                self._emit({"name": sp.name, "trace": sp.trace_id,
+                            "span": sp.span_id, "parent": sp.parent_id,
+                            "t0": sp.t0,
+                            "dur_s": time.perf_counter() - sp.t0,
+                            **sp.attrs})
 
     def emit(self, name: str, t0: float, t1: float, **attrs) -> None:
         """Record a span from explicit timestamps (e.g. queue wait:
         the region opened in ``enqueue`` and closed in ``flush``, on
         different call stacks, so a context manager cannot cover it).
-        Parented to the caller's current span."""
+        Parented to the caller's current span.  JSONL only: while a
+        profile captures, the duration becomes the ``<name>_s``
+        attribute of the current span's annotation."""
+        parent = self._current.get()
+        if parent is not None and parent.annotation is not None:
+            parent.annotation.set_metadata(**{f"{name}_s": t1 - t0})
         if not self.enabled:
             return
-        parent = self._current.get()
         sid = self._next_id()
         self._emit({"name": name,
                     "trace": parent.trace_id if parent else sid,

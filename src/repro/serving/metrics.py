@@ -74,8 +74,12 @@ COUNT_FIELDS = (
 #: accumulated wall time (seconds); each also records one histogram
 #: sample per ``add`` under phase = field name minus the ``_s`` suffix
 #: (``request_s`` is the scheduler's submit -> result latency, i.e.
-#: queueing + batching + execution as one end-to-end sample)
-TIME_FIELDS = ("solve_s", "compile_s", "execute_s", "request_s")
+#: queueing + batching + execution as one end-to-end sample;
+#: ``sched_queue_wait_s`` the part of it from submit to the start of the
+#: request's batch on its worker: waiting for the group to fill, for
+#: the dispatcher and for a free worker)
+TIME_FIELDS = ("solve_s", "compile_s", "execute_s", "request_s",
+               "sched_queue_wait_s")
 #: histogram metric name the phase/bucket latency samples land in
 LATENCY_METRIC = "serving_latency_seconds"
 

@@ -220,6 +220,10 @@ class LRU:
         until capacity pressure finds it)."""
         return self._d.pop(key, None)
 
+    def items(self) -> List[Tuple[Any, Any]]:
+        """Entries, least recently used first."""
+        return list(self._d.items())
+
     def __contains__(self, key) -> bool:
         return key in self._d
 

@@ -42,7 +42,8 @@ server's prefetch pool tracks load too.
 Everything the SLO story needs to be falsifiable is counted in the
 server's :class:`~repro.serving.metrics.ServingCounters`: per-request
 end-to-end latency histograms (``request`` phase, per batch bucket),
-launch-reason counters, and ``deadline_met``/``deadline_miss`` whose
+the part of it spent queued (``sched_queue_wait`` phase: submit to the
+start of the request's batch on a worker), launch-reason counters, and ``deadline_met``/``deadline_miss`` whose
 ratio is the *goodput* the load benchmark (benchmarks/bench_load.py)
 gates in CI.
 """
@@ -57,6 +58,7 @@ from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.trace import get_tracer
 from ..reliability.errors import ShedError
 from .bucketing import bucket_key, bucket_shape
 from .metrics import LATENCY_METRIC
@@ -254,8 +256,11 @@ class ContinuousScheduler:
                         return
                     self._cond.wait(timeout=self._next_wake_locked(now))
                     continue
-            for bshape, group, reason in launches:
-                self._exec.submit(self._run_batch, bshape, group, reason)
+            with get_tracer().span("sched.dispatch",
+                                   launches=len(launches)):
+                for bshape, group, reason in launches:
+                    self._exec.submit(self._run_batch, bshape, group,
+                                      reason)
 
     def _queued_locked(self) -> int:
         return sum(len(q) for q in self._queues.values())
@@ -269,7 +274,8 @@ class ContinuousScheduler:
         if target != self._workers_applied:
             self._workers_applied = target
             self.server.counters.add(worker_resizes=1)
-            self.server.resize_workers(target)
+            with get_tracer().span("sched.resize", workers=target):
+                self.server.resize_workers(target)
 
     def _launch_at(self, bshape: Shape, q: "Deque[_Pending]",
                    now: float) -> Tuple[float, str]:
@@ -361,13 +367,22 @@ class ContinuousScheduler:
     # -----------------------------------------------------------------
     def _run_batch(self, bshape: Shape, group: List[_Pending],
                    reason: str) -> None:
+        with get_tracer().span("sched.batch", size=len(group),
+                               reason=reason):
+            self._run_batch_traced(bshape, group, reason)
+
+    def _run_batch_traced(self, bshape: Shape, group: List[_Pending],
+                          reason: str) -> None:
+        bkey = bucket_key(bshape, self.policy.bucket_n(len(group)))
         if self.fault_injector is not None:
-            spec = self.fault_injector.check(
-                "worker", key=bucket_key(bshape,
-                                         self.policy.bucket_n(len(group))))
+            spec = self.fault_injector.check("worker", key=bkey)
             if spec is not None:
                 self._worker_died(bshape, group, spec)
                 return
+        start = time.perf_counter()
+        for p in group:
+            self.server.counters.add(_bucket=bkey,
+                                     sched_queue_wait_s=start - p.t_submit)
         try:
             outs = self.server.infer_batch([p.x for p in group])
         except BaseException as exc:  # noqa: BLE001 — must resolve futs
@@ -375,8 +390,6 @@ class ContinuousScheduler:
                 p.fut.set_exception(exc)
         else:
             done = time.perf_counter()
-            bkey = bucket_key(bshape,
-                              self.policy.bucket_n(len(group)))
             met = miss = 0
             for p in group:
                 self.server.counters.add(_bucket=bkey,

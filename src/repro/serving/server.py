@@ -428,8 +428,7 @@ class PlanServer:
         may differ from the argument after a quarantine re-solve.
         """
         if not self.guard_outputs and self.fault_injector is None:
-            return {nid: np.asarray(v)
-                    for nid, v in cnet(xb).items()}, cnet
+            return _run(cnet, xb), cnet
         bkey = bucket_key(bshape, nb)
         attempts = 0
         while True:
@@ -437,8 +436,7 @@ class PlanServer:
             failure: Optional[BaseException] = None
             culprit: Optional[str] = None
             try:
-                out = {nid: np.asarray(v)
-                       for nid, v in cnet(xb).items()}
+                out = _run(cnet, xb)
             except Exception as exc:
                 failure = exc
             if self.fault_injector is not None:
@@ -466,7 +464,9 @@ class PlanServer:
             if out is not None:
                 if not self.guard_outputs:
                     return out, cnet
-                if all(np.isfinite(v).all() for v in out.values()):
+                with get_tracer().span("guard"):
+                    finite = all(np.isfinite(v).all() for v in out.values())
+                if finite:
                     return out, cnet
                 failure = KernelFailure(bkey, culprit,
                                         "non-finite outputs")
@@ -582,16 +582,17 @@ class PlanServer:
             cnet = self.compiled_for(x.shape)
             bshape = bucket_shape(x.shape, self.policy)
             bkey = bucket_key(bshape, cnet.batch)
-            pads = [(0, b - s) for b, s in zip(bshape, x.shape)]
-            xb = np.pad(x, pads)
-            if cnet.batch > 1:
-                # a policy whose batch bucket for n=1 is > 1 (linear
-                # batch mode, min_n > 1) hands the single request a
-                # batched executable: embed the image as row 0, zero
-                # rows pad
-                xb = np.concatenate(
-                    [xb[None], np.zeros((cnet.batch - 1, *bshape),
-                                        np.float32)])
+            with tracer.span("prepare"):
+                pads = [(0, b - s) for b, s in zip(bshape, x.shape)]
+                xb = np.pad(x, pads)
+                if cnet.batch > 1:
+                    # a policy whose batch bucket for n=1 is > 1 (linear
+                    # batch mode, min_n > 1) hands the single request a
+                    # batched executable: embed the image as row 0, zero
+                    # rows pad
+                    xb = np.concatenate(
+                        [xb[None], np.zeros((cnet.batch - 1, *bshape),
+                                            np.float32)])
             expected = self._expected_out_shapes(x.shape)
             t0 = time.perf_counter()
             with tracer.span("execute", bucket=bkey):
@@ -659,10 +660,11 @@ class PlanServer:
                 seen_specs.add((bshape, nb))
             else:
                 cnet = self.compiled_for(bshape, n=nb)
-            xb = np.zeros((nb, *bshape), np.float32)
-            for row, i in enumerate(chunk):
-                x = imgs[i]
-                xb[row, :x.shape[0], :x.shape[1], :x.shape[2]] = x
+            with tracer.span("prepare"):
+                xb = np.zeros((nb, *bshape), np.float32)
+                for row, i in enumerate(chunk):
+                    x = imgs[i]
+                    xb[row, :x.shape[0], :x.shape[1], :x.shape[2]] = x
             bkey = bucket_key(bshape, nb)
             t0 = time.perf_counter()
             with tracer.span("execute", bucket=bkey,
@@ -745,7 +747,23 @@ class PlanServer:
         #: histogram-backed latency percentiles per phase — entries
         #: like "execute[bucket=8x3x32x32]" split them per batch bucket
         d["phases"] = self.counters.phase_quantiles()
+        #: XLA executables built in this process (not only this server's)
+        d.update(plan_mod.xla_compile_stats())
         return d
+
+    def op_scopes(self) -> Dict[str, str]:
+        """``{HLO instruction name: scope}`` over the live executables
+        (:meth:`CompiledNet.op_scopes`): the PBQP node or layout edge
+        each device op of a profile belongs to.  Instruction names are
+        per executable; where two executables share one, the most
+        recently used wins."""
+        with self._lock:
+            live = self._compiled.items()
+        out: Dict[str, str] = {}
+        for (c, h, w, nb), cnet in live:
+            out.update(cnet.op_scopes((c, h, w) if cnet.batch == 1
+                                      else (nb, c, h, w)))
+        return out
 
     def metrics_text(self) -> str:
         """Prometheus text exposition of this server's registry."""
@@ -762,6 +780,16 @@ class PlanServer:
         for _, fut, _ in pending:
             fut.cancel()
         self._pool.shutdown(wait=True)
+
+
+def _run(cnet: CompiledNet, xb: np.ndarray) -> Dict[str, np.ndarray]:
+    """Call the executable, then copy its outputs to the host: ``dispatch``
+    returns once the call is queued, ``fetch`` waits for the device."""
+    tracer = get_tracer()
+    with tracer.span("dispatch"):
+        outs = cnet(xb)
+    with tracer.span("fetch"):
+        return {nid: np.asarray(v) for nid, v in outs.items()}
 
 
 def _block(outs) -> None:

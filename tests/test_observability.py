@@ -245,6 +245,61 @@ class TestTracer:
         assert {"trace", "span", "parent", "t0", "dur_s"} <= set(recs[0])
 
 
+    @pytest.mark.parametrize("capturing", [False, True])
+    @pytest.mark.parametrize("jsonl", [False, True])
+    def test_profiler_annotation_while_capturing(self, monkeypatch,
+                                                 capturing, jsonl):
+        """A span opens a profiler annotation exactly while a capture
+        runs, with or without the JSONL sink, and the JSONL records do
+        not change."""
+        from repro.obs import trace as trace_mod
+        opened = []
+
+        class FakeAnnotation:
+            @staticmethod
+            def is_enabled():
+                return capturing
+
+            def __init__(self, name, **meta):
+                self.name, self.meta, self.closed = name, dict(meta), False
+                opened.append(self)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                self.closed = True
+
+            def set_metadata(self, **meta):
+                self.meta.update(meta)
+
+        monkeypatch.setattr(trace_mod, "_annotation_cls", FakeAnnotation)
+        records = []
+        tr = Tracer(records, enabled=jsonl)
+        with tr.span("outer", a=1) as outer:
+            with tr.span("inner") as inner:
+                inner.set(b=2)
+            tr.emit("event", 1.0, 1.5)
+        assert (outer is NULL_SPAN) == (not capturing and not jsonl)
+        if capturing:
+            assert [(o.name, o.closed) for o in opened] == \
+                [("outer", True), ("inner", True)]
+            assert opened[0].meta == {"a": 1, "event_s": 0.5}
+            assert opened[1].meta == {"b": 2}
+        else:
+            assert opened == []
+        if jsonl:
+            assert [r["name"] for r in records] == ["inner", "event",
+                                                    "outer"]
+            inner_r, event_r, outer_r = records
+            assert set(outer_r) == {"name", "trace", "span", "parent",
+                                    "t0", "dur_s", "a"}
+            assert inner_r["b"] == 2 and "b" not in outer_r
+            assert inner_r["parent"] == event_r["parent"] == outer_r["span"]
+        else:
+            assert records == []
+
+
 # ---------------------------------------------------------------------
 # spans through the serving stack
 # ---------------------------------------------------------------------
@@ -325,6 +380,51 @@ class TestServingSpans:
             assert w["trace"] == flush["trace"]
             assert w["dur_s"] >= 0.0
 
+    def test_request_phase_spans(self, sink):
+        """``prepare`` under the request span; ``dispatch``, ``fetch``
+        and ``guard`` under ``execute``, on both request paths."""
+        srv = _server()
+        try:
+            srv.infer(np.zeros((3, 12, 12), np.float32))
+            srv.infer_batch([np.zeros((3, 12, 12), np.float32)] * 2)
+        finally:
+            srv.close()
+        for root_name in ("infer", "infer_batch"):
+            root = _by_name(sink, root_name)[0]
+            mine = [r for r in sink if r["trace"] == root["trace"]]
+            execute = [r for r in mine if r["name"] == "execute"][-1]
+            prepare = [r for r in mine if r["name"] == "prepare"]
+            assert prepare and all(r["parent"] == root["span"]
+                                   for r in prepare)
+            for name in ("dispatch", "fetch", "guard"):
+                (r,) = [r for r in mine if r["name"] == name
+                        and r["parent"] == execute["span"]]
+                assert r["dur_s"] >= 0.0
+
+    def test_spans_reach_a_profiler_capture(self, tmp_path):
+        """With the JSONL sink off, a ``jax.profiler`` capture holds the
+        request's spans on its host planes."""
+        import jax
+        from jax.profiler import ProfileData
+
+        srv = _server(guard_outputs=True)
+        x = np.zeros((3, 12, 12), np.float32)
+        try:
+            srv.infer(x)  # solve + compile outside the capture
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                srv.infer(x)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            srv.close()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert {"infer", "prepare", "execute", "dispatch", "fetch", "guard",
+                "crop"} <= names
+
     def test_stats_phases_percentiles(self, sink):
         srv = _server()
         try:
@@ -343,25 +443,199 @@ class TestServingSpans:
 
 
 # ---------------------------------------------------------------------
+# the continuous scheduler's spans and queue wait
+# ---------------------------------------------------------------------
+class TestSchedulerTracing:
+    def test_queue_wait_one_sample_per_request(self, sink, monkeypatch):
+        """``sched_queue_wait_s`` gains one sample per request, each
+        between 0 and that request's ``request_s``; each batch runs
+        under a ``sched.batch`` span with its size and reason."""
+        import threading as th
+
+        from repro.serving import ContinuousScheduler
+        srv = _server()
+        seen = []
+        add = srv.counters.add
+
+        def spy(_bucket=None, **kw):
+            for k in ("sched_queue_wait_s", "request_s"):
+                if k in kw:
+                    seen.append((th.get_ident(), k, kw[k]))
+            add(_bucket=_bucket, **kw)
+
+        monkeypatch.setattr(srv.counters, "add", spy)
+        sched = ContinuousScheduler(srv, batch_window_s=0.005)
+        n = 7
+        try:
+            sched.prewarm([(3, 12, 12)], batches=(1, 2, 4))
+            seen.clear()
+            sink.clear()
+            futs = [sched.submit(np.zeros((3, 12, 12), np.float32))
+                    for _ in range(n)]
+            for f in futs:
+                f.result(timeout=60)
+            s = sched.stats()
+        finally:
+            sched.close()
+            srv.close()
+        waits = [(t, v) for t, k, v in seen if k == "sched_queue_wait_s"]
+        lats = [(t, v) for t, k, v in seen if k == "request_s"]
+        assert len(waits) == len(lats) == n
+        # a worker records a batch's waits, then its latencies, in the
+        # group's order: pair them per thread
+        for tid in {t for t, _ in waits}:
+            mine_w = [v for t, v in waits if t == tid]
+            mine_l = [v for t, v in lats if t == tid]
+            assert all(0.0 <= w <= r for w, r in zip(mine_w, mine_l))
+        assert s["phases"]["sched_queue_wait"]["count"] >= n
+        batches = _by_name(sink, "sched.batch")
+        assert sum(b["size"] for b in batches) == n
+        assert {b["reason"] for b in batches} <= {"full", "deadline",
+                                                  "window"}
+        for r in _by_name(sink, "infer_batch"):
+            assert r["parent"] in {b["span"] for b in batches}
+        assert _by_name(sink, "sched.dispatch")
+
+
+# ---------------------------------------------------------------------
 # compile counter (satellite: thread-safe, registry-backed)
 # ---------------------------------------------------------------------
 class TestCompileCount:
     def test_concurrent_compiles_counted_exactly(self):
-        net = conv_stack((3, 8, 8), depth=1, width=4)
-        sel = select_pbqp(net, CM)
-        params = net.init_params(0)
-        before = plan_mod.compile_count()
+        """Every XLA compile JAX reports lands in the process-wide
+        counter exactly once, from any thread."""
+        import jax
+        import jax.numpy as jnp
+
+        before = plan_mod.xla_compile_stats()
         n_threads = 6
 
-        def worker():
-            compile_plan(sel, params, jit=False)
+        def worker(k):
+            jax.jit(lambda x: x * k + 1).lower(
+                jax.ShapeDtypeStruct((4,), jnp.float32)).compile()
 
-        ts = [threading.Thread(target=worker) for _ in range(n_threads)]
+        ts = [threading.Thread(target=worker, args=(k,))
+              for k in range(n_threads)]
         for t in ts:
             t.start()
         for t in ts:
             t.join()
-        assert plan_mod.compile_count() == before + n_threads
+        after = plan_mod.xla_compile_stats()
+        assert after["xla_compiles"] == before["xla_compiles"] + n_threads
+        assert after["xla_compile_s"] > before["xla_compile_s"]
+
+
+# ---------------------------------------------------------------------
+# named scopes per PBQP node and conversion edge
+# ---------------------------------------------------------------------
+def _alternating_plan(ops: bool):
+    """Pointwise convs alternating HWC and CHW primitives, so every edge
+    carries a conversion chain; with ``ops`` a relu after each."""
+    from repro.core.graph import Net, relu
+    from repro.core.primitives import registry
+    from repro.core.selection import select_fixed
+
+    by_name = {p.name: p for p in registry()}
+    net = Net("alt")
+    x = net.input("data", (8, 12, 12))
+    for i in range(4):
+        x = net.conv(f"conv{i}", x, k=1, m=8)
+        if ops:
+            x = net.op(f"relu{i}", [x], relu())
+    pick = {n.id: by_name["pw_gemm_n_hwc" if i % 2 == 0 else
+                          "pw_gemm_n_chw"]
+            for i, n in enumerate(net.conv_nodes())}
+    return select_fixed(net, CM, pick, "alt"), net.init_params(0)
+
+
+class TestOpScopes:
+    def test_every_node_and_edge_named_and_nothing_else(self):
+        sel, params = _alternating_plan(ops=False)
+        cnet = compile_plan(sel, params)
+        scopes = cnet.scopes()
+        edges = {f"edge:{s}->{d}" for (s, d), c in sel.conversions.items()
+                 if c}
+        assert len(edges) == 4  # data->conv0 and three between convs
+        assert scopes == edges | {f"node:conv{i}" for i in range(4)}
+        got = cnet.op_scopes((8, 12, 12))
+        assert set(got.values()) == scopes
+
+    def test_scopes_only_name_the_plan(self):
+        """With op nodes XLA fuses some scopes away; whatever is named
+        belongs to the plan, every scope is opened, and the batched
+        executable is mapped too."""
+        import jax
+        sel, params = _alternating_plan(ops=True)
+        for batch, shape in ((1, (8, 12, 12)), (4, (4, 8, 12, 12))):
+            s = sel if batch == 1 else select_pbqp(
+                sel.net.with_batch(batch), CM)
+            cnet = compile_plan(s, s.net.init_params(0), batch=batch)
+            got = cnet.op_scopes(shape)
+            assert got and set(got.values()) <= cnet.scopes()
+            lowered = cnet.fn.lower(
+                jax.ShapeDtypeStruct(shape, np.float32),
+                cnet.params).as_text(debug_info=True)
+            assert all(sc in lowered for sc in cnet.scopes())
+
+    def test_scopes_change_metadata_only(self, monkeypatch):
+        import contextlib
+        import re
+
+        import jax
+        sel, params = _alternating_plan(ops=True)
+
+        def program(cnet):
+            text = cnet.fn.lower(jax.ShapeDtypeStruct((8, 12, 12),
+                                                      np.float32),
+                                 cnet.params).compile().as_text()
+            text = text.split("\nFileNames")[0]
+            return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+        scoped = program(compile_plan(sel, params))
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        assert program(compile_plan(sel, params)) == scoped
+
+    def test_hlo_scopes_from_bodies_and_consumers(self):
+        """A fusion without a scope takes its body's; an unscoped copy
+        takes its consumer's; nothing outside ``scopes`` is named."""
+        hlo = "\n".join([
+            "HloModule m",
+            "",
+            "%fused_computation.1 (p: f32[4]) -> f32[4] {",
+            "  %p = f32[4]{0} parameter(0)",
+            '  ROOT %n.1 = f32[4]{0} negate(%p), '
+            'metadata={op_name="jit(run)/vmap(node:c1)/neg"}',
+            "}",
+            "",
+            "ENTRY %main (x: f32[4]) -> f32[4] {",
+            '  %x = f32[4]{0} parameter(0), metadata={op_name="x"}',
+            '  %copy.2 = f32[4]{0} copy(%x), metadata={op_name="x"}',
+            "  %fusion.3 = f32[4]{0} fusion(%copy.2), kind=kLoop, "
+            "calls=%fused_computation.1",
+            '  %t.4 = f32[4]{0} transpose(%fusion.3), dimensions={0}, '
+            'metadata={op_name="jit(run)/edge:c1->c2/transpose"}',
+            '  ROOT %s.5 = f32[4]{0} sine(%t.4), '
+            'metadata={op_name="jit(run)/node:other/sin"}',
+            "}",
+        ])
+        got = plan_mod.hlo_op_scopes(hlo, {"node:c1", "edge:c1->c2"})
+        assert got == {"x": "node:c1", "copy.2": "node:c1",
+                       "fusion.3": "node:c1", "t.4": "edge:c1->c2"}
+
+    def test_server_op_scopes_cover_live_executables(self):
+        srv = _server()
+        try:
+            srv.infer(np.zeros((3, 12, 12), np.float32))
+            srv.infer_batch([np.zeros((3, 12, 12), np.float32)] * 2)
+            got = srv.op_scopes()
+            scopes = set()
+            for n in (1, 2):
+                scopes |= srv.compiled_for((3, 12, 12), n).scopes()
+        finally:
+            srv.close()
+        assert got and set(got.values()) <= scopes
+        assert any(v.startswith("node:") for v in got.values())
 
 
 # ---------------------------------------------------------------------
