@@ -225,17 +225,25 @@ def avgpool(k: int, stride: int, pad: int = 0) -> OpDef:
 
 def lrn(size: int = 5, alpha: float = 1e-4, beta: float = 0.75,
         bias: float = 1.0) -> OpDef:
-    """AlexNet/GoogleNet local response normalisation across channels."""
+    """AlexNet/GoogleNet local response normalisation across channels.
+
+    The window sum over channels is ``size`` shifted slices of the
+    zero-padded float32 square, added in float32: XLA fuses square, pad,
+    slices, sum and power into one elementwise loop, where a
+    ``reduce_window`` over the channel axis stays a separate, slow op on
+    the TPU (most of all under ``vmap``)."""
     def fn(xs, layout, p):
         x = xs[0]
         ca = _c_axis(layout)
-        sq = x * x
-        window = [1] * x.ndim
-        window[ca] = size
+        c = x.shape[ca]
+        xf = x.astype(jnp.float32)
         pads = [(0, 0)] * x.ndim
         pads[ca] = (size // 2, size // 2)
-        s = lax.reduce_window(sq, 0.0, lax.add, window, [1] * x.ndim, pads)
-        return x / (bias + (alpha / size) * s) ** beta
+        padded = jnp.pad(xf * xf, pads)
+        s = lax.slice_in_dim(padded, 0, c, axis=ca)
+        for i in range(1, size):
+            s = s + lax.slice_in_dim(padded, i, i + c, axis=ca)
+        return (xf / (bias + (alpha / size) * s) ** beta).astype(x.dtype)
 
     return OpDef(f"lrn{size}", lambda s: s[0], fn)
 

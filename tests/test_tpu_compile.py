@@ -189,6 +189,16 @@ def test_flash_attention_tinyllama(compile_for, causal):
                                      (4, 512, 64))) == 1
 
 
+def test_lrn_batched_has_no_reduce_window(compile_for):
+    """GoogLeNet's norm2 at batch 8 compiles to elementwise fusion: the
+    channel window sum leaves no ``reduce-window`` instruction."""
+    from repro.core.graph import lrn
+    op, layout = lrn(), LAYOUT_BY_NAME["CHW"]
+    compiled = compile_for(jax.vmap(lambda x: op.fn([x], layout, None)),
+                           (8, 192, 56, 56))
+    assert "reduce-window" not in compiled.as_text()
+
+
 @pytest.mark.parametrize("net,node", [
     ("googlenet", "conv1"), ("googlenet", "conv2"),
     ("googlenet", "i3a_5x5"), ("googlenet", "i4a_1x1"),
