@@ -3,7 +3,8 @@
 Two operations (a multiply and an add) per multiply-accumulate of every
 conv and fc layer, counted as the direct algorithm does them, whatever
 primitive the plan runs: a Winograd or im2col conv does the same model
-work.  Pools, LRN, ReLU and the softmax are left out.
+work.  Pools, LRN, ReLU, the ``concat`` and ``add`` joins and the softmax
+are left out.
 """
 from __future__ import annotations
 

@@ -9,12 +9,15 @@ It imports nothing of the program under test.
 A layer is a dict: ``name``, ``op``, ``inputs`` (names of earlier layers,
 ``"data"`` for the image) and the op's sizes:
 
-* ``conv``: ``k``, ``m``, ``stride``, ``pad`` -- cross-correlation plus bias;
+* ``conv``: ``k``, ``m``, ``stride``, ``pad``, optional ``gain`` (1.0) --
+  cross-correlation plus bias;
 * ``relu``;
 * ``maxpool``: ``k``, ``stride``, ``pad`` (padding never wins the max);
 * ``lrn``: ``size``, ``alpha``, ``beta``, ``bias`` -- across channels,
   ``x / (bias + alpha/size * sum x^2) ** beta``;
 * ``concat``: along channels;
+* ``add``: the elementwise sum of two or more inputs of one shape (a
+  residual join);
 * ``gap``: global average pool to ``(C, 1, 1)``;
 * ``fc``: ``out``, ``relu`` -- flattens each image in (C, H, W) order.
 
@@ -26,6 +29,17 @@ derives from its ``params_seed``: one ``numpy.random.default_rng(seed)``
 drawn layer by layer in list order; a conv draws its ``(M, C, K, K)``
 weights from N(0, 2/(C K K)) and then its bias from N(0, 0.01^2); an fc
 draws its ``(in, out)`` weights from N(0, 2/in) and has a zero bias.
+
+A conv's ``gain`` scales the std of its weights' draw and nothing else:
+``rng.normal(0, gain * sqrt(2/(C K K)))``, from the same stream in the
+same list order, with the bias drawn as before.  It is a folded
+inference BatchNorm whose scale gamma/sigma is ``gain`` in every
+channel, as on a residual branch's last conv: without it each identity
+join of the He draw about doubles the activations' second moment, and a
+deep residual net's softmax keeps a single class.  A served net drawn
+with ``gain`` has to draw the same bits in ``Net.init_params``: the
+same stream, in this list's order.  A conv without ``gain`` draws with
+the std ``sqrt(2/(C K K))`` itself, bit for bit.
 """
 from __future__ import annotations
 
@@ -57,6 +71,11 @@ def shapes(layers: Sequence[Layer], input_chw: Tuple[int, int, int]
             out[ly["name"]] = (c, h, w)
         elif op == "concat":
             out[ly["name"]] = (sum(s[0] for s in ins), h, w)
+        elif op == "add":
+            if len(ins) < 2 or any(s != ins[0] for s in ins):
+                raise ValueError(f"add {ly['name']} needs two or more inputs "
+                                 f"of one shape, got {ins}")
+            out[ly["name"]] = ins[0]
         elif op == "gap":
             out[ly["name"]] = (c, 1, 1)
         elif op == "fc":
@@ -75,7 +94,8 @@ def init_params(layers: Sequence[Layer], input_chw: Tuple[int, int, int],
     for ly in layers:
         if ly["op"] == "conv":
             c = shp[ly["inputs"][0]][0]
-            std = float(np.sqrt(2.0 / (c * ly["k"] * ly["k"])))
+            std = ly.get("gain", 1.0) * float(
+                np.sqrt(2.0 / (c * ly["k"] * ly["k"])))
             params[ly["name"]] = {
                 "w": rng.normal(0, std, size=(ly["m"], c, ly["k"], ly["k"]))
                         .astype(np.float32),
@@ -172,6 +192,8 @@ def forward(layers: Sequence[Layer], params, x, precision: str = "float32"):
             y = v / (ly["bias"] + ly["alpha"] / ly["size"] * sq) ** ly["beta"]
         elif op == "concat":
             y = jnp.concatenate(ins, axis=1)
+        elif op == "add":
+            y = sum(ins[1:], ins[0])
         elif op == "gap":
             y = jnp.mean(v, axis=(2, 3), keepdims=True)
         elif op == "fc":

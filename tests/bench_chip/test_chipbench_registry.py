@@ -1,12 +1,13 @@
 """BENCHMARK.json against the benchmark contract, and the harness finding
 every piece of a cell by name from files alone."""
 import json
+import pathlib
 import re
 import shutil
 
 import pytest
 
-from benchmarks.chip import harness
+from benchmarks.chip import flops, harness, reference
 
 BENCH = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -73,33 +74,54 @@ def _snapshot(root):
             for p in sorted(root.rglob("*")) if p.is_file()}
 
 
-def test_a_new_config_traffic_and_metric_are_files_alone(tmp_path):
+def _vgg16_384(chip):
+    cfg = json.loads((chip / "configs" / "vgg16.json").read_text())
+    cfg.update(name="vgg16_384", input_chw=[3, 384, 384])
+    return cfg, (chip / "configs" / "vgg16.py").read_text()
+
+
+def _resnet50(chip):
+    # a residual net: its list has ``add`` joins and convs with a ``gain``
+    py = pathlib.Path(__file__).with_name("resnet50_v15.py")
+    mod = harness.load_module(py, "resnet50_v15")
+    return dict(mod.CONFIG, name="resnet50"), py.read_text()
+
+
+@pytest.mark.parametrize("config, make, macs", [
+    ("vgg16_384", _vgg16_384, 45_423_165_440),
+    ("resnet50", _resnet50, 4_089_184_256),
+], ids=["vgg16_384", "resnet50"])
+def test_a_new_config_traffic_and_metric_are_files_alone(tmp_path, config,
+                                                         make, macs):
     root = _copy_tree(tmp_path)
     chip = root / "benchmarks" / "chip"
     before = _snapshot(chip)
-    cfg = json.loads((chip / "configs" / "vgg16.json").read_text())
-    cfg.update(name="vgg16_384", input_chw=[3, 384, 384])
-    (chip / "configs" / "vgg16_384.json").write_text(json.dumps(cfg))
-    shutil.copy(chip / "configs" / "vgg16.py", chip / "configs" / "vgg16_384.py")
+    cfg, py = make(chip)
+    (chip / "configs" / f"{config}.json").write_text(json.dumps(cfg))
+    (chip / "configs" / f"{config}.py").write_text(py)
     (chip / "traffic" / "batch32_closed.json").write_text(
         json.dumps({"driver": "closed_batch", "batch": 32}))
     (chip / "metrics" / "calls_per_s.b32.py").write_text(
         "def read(run):\n    return 42.0\n")
     bench = json.loads((root / "BENCHMARK.json").read_text())
-    bench["configs"].append({"name": "vgg16_384", "source": "x",
-                             "file": "benchmarks/chip/configs/vgg16_384.json",
+    bench["configs"].append({"name": config, "source": "x",
+                             "file": f"benchmarks/chip/configs/{config}.json",
                              "reduced": [], "why": "x"})
-    bench["workloads"].append({"name": "vgg16_384.b32", "config": "vgg16_384",
+    bench["workloads"].append({"name": f"{config}.b32", "config": config,
                                "traffic": "batch32_closed", "chips": 1,
                                "why": "x"})
     bench["per_layer"].append({"name": "calls_per_s.b32", "unit": "1/s",
                                "better": "higher", "source": "host_clock",
                                "layer": "executable", "moves": "images_per_s",
-                               "workloads": ["vgg16_384.b32"]})
+                               "workloads": [f"{config}.b32"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
-    cell = harness.load_cell("vgg16_384.b32", root)
-    assert cell.config["input_chw"] == [3, 384, 384]
+    cell = harness.load_cell(f"{config}.b32", root)
+    chw = tuple(cell.config["input_chw"])
+    assert chw == tuple(cfg["input_chw"])
+    assert reference.shapes(cell.layers, chw)[cell.layers[-1]["name"]] == (
+        1000, 1, 1)
+    assert flops.macs(cell.layers, chw) == macs
     assert cell.traffic["batch"] == 32
     assert cell.driver.__name__.endswith("closed_batch")
     assert [m.name for m in cell.per_layer] == ["solve_ms", "calls_per_s.b32"]
