@@ -2,9 +2,11 @@
 
 Convolution layers carry a :class:`Scenario` and are assigned primitives
 by the PBQP selection.  All other layers ("op" nodes: activation,
-pooling, LRN, concat, FC, ...) follow the paper's simplifying
-assumption: they are layout-polymorphic dummy nodes with zero cost whose
-PBQP domain is the set of data layouts they accept.
+pooling, LRN, the concat and residual ``add`` joins, FC, ...) follow the
+paper's simplifying assumption: they are layout-polymorphic dummy nodes
+with zero cost whose PBQP domain is the set of data layouts they accept.
+A join is one op node with several producers, so the PBQP ties their
+output layouts to the one layout it runs in.
 """
 from __future__ import annotations
 
@@ -20,7 +22,7 @@ from .layouts import LAYOUT_BY_NAME, Layout
 from .scenario import Scenario
 
 __all__ = ["Net", "Node", "OpDef", "relu", "maxpool", "avgpool", "lrn",
-           "concat", "fc", "global_avgpool", "softmax", "identity"]
+           "concat", "add", "fc", "global_avgpool", "softmax", "identity"]
 
 #: layouts an op node accepts by default (all unblocked permutations that
 #: primitives actually produce; blocked layouts are op-specific)
@@ -48,6 +50,8 @@ class Node:
     scn: Optional[Scenario] = None
     op: Optional[OpDef] = None
     out_shape: Tuple[int, ...] = ()  # logical (C, H, W) or (F,) after FC
+    #: a conv's weight-draw scale (:meth:`Net.conv`); no cost, no plan
+    gain: float = 1.0
 
 
 class Net:
@@ -72,11 +76,15 @@ class Net:
         return self._add(Node(id, "input", [], out_shape=shape_chw))
 
     def conv(self, id: str, src: str, *, k: int, m: int, stride: int = 1,
-             pad: int = -1) -> str:
+             pad: int = -1, gain: float = 1.0) -> str:
+        """A conv node.  ``gain`` scales the std of its seeded weight draw
+        (:meth:`init_params`): a folded inference BatchNorm whose
+        gamma/sigma is ``gain`` in every channel, as on a residual
+        branch's last conv.  It changes no scenario, cost or plan."""
         c, h, w = self.nodes[src].out_shape
         scn = Scenario(c=c, h=h, w=w, stride=stride, k=k, m=m, pad=pad)
         return self._add(Node(id, "conv", [src], scn=scn,
-                              out_shape=scn.out_shape_chw))
+                              out_shape=scn.out_shape_chw, gain=gain))
 
     def op(self, id: str, srcs: Sequence[str], opdef: OpDef) -> str:
         shapes = [self.nodes[s].out_shape for s in srcs]
@@ -121,7 +129,7 @@ class Net:
             nd = self.nodes[nid]
             scn = nd.scn.with_(n=n) if nd.kind == "conv" else nd.scn
             new.nodes[nid] = Node(nd.id, nd.kind, list(nd.inputs),
-                                  scn, nd.op, nd.out_shape)
+                                  scn, nd.op, nd.out_shape, nd.gain)
             new._order.append(nid)
         return new
 
@@ -148,14 +156,16 @@ class Net:
         return h.hexdigest()[:16]
 
     def init_params(self, seed: int = 0) -> Dict[str, Dict[str, np.ndarray]]:
-        """He-initialised raw weights per node (logical layouts)."""
+        """He-initialised raw weights per node (logical layouts), one
+        stream drawn in node order; a conv's weights at ``gain`` times
+        the He std, its bias unscaled."""
         rng = np.random.default_rng(seed)
         params: Dict[str, Dict[str, np.ndarray]] = {}
         for nid in self._order:
             node = self.nodes[nid]
             if node.kind == "conv":
                 s = node.scn
-                std = float(np.sqrt(2.0 / (s.c * s.k * s.k)))
+                std = node.gain * float(np.sqrt(2.0 / (s.c * s.k * s.k)))
                 params[nid] = {
                     "w": rng.normal(0, std, size=s.weight_shape)
                             .astype(np.float32),
@@ -258,6 +268,23 @@ def concat() -> OpDef:
         return jnp.concatenate(xs, axis=_c_axis(layout))
 
     return OpDef("concat", shape_fn, fn)
+
+
+def add() -> OpDef:
+    """Elementwise sum of two or more inputs of one shape (residual joins).
+
+    All inputs arrive in the one layout the node runs in, so the PBQP
+    ties a block's last conv to its shortcut's producer."""
+    def shape_fn(shapes):
+        if len(shapes) < 2 or any(s != shapes[0] for s in shapes):
+            raise ValueError(f"add needs two or more inputs of one shape, "
+                             f"got {list(shapes)}")
+        return shapes[0]
+
+    def fn(xs, layout, p):
+        return sum(xs[1:], xs[0])
+
+    return OpDef("add", shape_fn, fn)
 
 
 def global_avgpool() -> OpDef:

@@ -66,7 +66,8 @@ def main(argv=None) -> int:
                     help="named scenario-bucket grid")
     ap.add_argument("--net", nargs="*", default=None,
                     help="calibrate exactly these networks' buckets "
-                         "(alexnet, vgg-a..e, googlenet) instead of a grid")
+                         "(alexnet, vgg-a..e, googlenet, resnet50) instead "
+                         "of a grid")
     ap.add_argument("--scale", type=float, default=0.25,
                     help="network scale factor for --net")
     ap.add_argument("--batches", nargs="+", type=int, default=[1],

@@ -202,7 +202,9 @@ def test_lrn_batched_has_no_reduce_window(compile_for):
 @pytest.mark.parametrize("net,node", [
     ("googlenet", "conv1"), ("googlenet", "conv2"),
     ("googlenet", "i3a_5x5"), ("googlenet", "i4a_1x1"),
-    ("vgg-a", "conv3_2"), ("alexnet", "conv1")])
+    ("vgg-a", "conv3_2"), ("alexnet", "conv1"),
+    # ResNet-50 v1.5: a stage-4 1x1 reduce, a stride-2 3x3
+    ("resnet50", "s4b2_a"), ("resnet50", "s3b1_b")])
 def test_offered_pallas_primitives(compile_for, chip_wrappers, net, node):
     """Every Pallas primitive the registry offers at a real layer
     compiles through its serving wrapper, as a plan would run it."""
