@@ -30,6 +30,7 @@ docs/distributed.md.
 """
 from __future__ import annotations
 
+import math
 import re
 import time
 from dataclasses import dataclass
@@ -48,7 +49,8 @@ from .primitives import convert_layout
 from .selection import Placement, SelectionResult, pp_microbatches
 
 __all__ = ["compile_plan", "CompiledNet", "measure", "xla_compile_stats",
-           "mesh_shape_dict", "node_scope", "edge_scope"]
+           "mesh_shape_dict", "node_scope", "edge_scope", "packed_shape",
+           "unpack_images"]
 
 
 def mesh_shape_dict(mesh) -> Dict[str, int]:
@@ -78,6 +80,26 @@ def xla_compile_stats() -> Dict[str, float]:
     """Executables XLA has built in this process, and their seconds."""
     return {"xla_compiles": _XLA_COMPILES.value,
             "xla_compile_s": float(_XLA_COMPILE_S.value)}
+
+
+def packed_shape(chw, batch: int = 1) -> tuple:
+    """Shape of the float32 array a single-device executable takes for
+    ``batch`` images of shape ``chw``: each image's values in row-major
+    order, then zeros, in ``K`` rows of 128 lanes with ``K`` a multiple
+    of 8.  A TPU tiles the last two dims of a float32 array by 8 x 128,
+    so the tiled layout of this shape is its row-major bytes: nothing
+    needs rearranging on the host.  ``(K, 128)`` for one image,
+    else ``(batch, K, 128)``."""
+    k = -(-math.prod(chw) // 1024) * 8
+    return (k, 128) if batch == 1 else (batch, k, 128)
+
+
+def unpack_images(xp, chw, batch: int = 1):
+    """The (C, H, W) image, or (N, C, H, W) images, inside a packed array
+    (:func:`packed_shape`): a view of a numpy array, a slice and reshape
+    of a traced one."""
+    flat = xp.reshape(batch, -1)[:, :math.prod(chw)]
+    return flat.reshape(tuple(chw) if batch == 1 else (batch, *chw))
 
 
 def node_scope(nid: str) -> str:
@@ -185,9 +207,47 @@ class CompiledNet:
     #: kept so obs.drift.InstrumentedNet can rebuild the same walk with
     #: per-node timing.  None only on hand-constructed instances.
     makers: Optional[Dict[str, Callable]] = None
+    #: (C, H, W) of one input image
+    image_shape: tuple = ()
+    #: ``(packed x, params) -> outputs``: ``fn`` behind an unpack of its
+    #: input (:func:`packed_shape`), the executable a server runs.  None
+    #: on mesh executables, which take ``fn``'s input.
+    entry: Optional[Callable] = None
+
+    @property
+    def in_shape(self) -> tuple:
+        """Shape of the float32 array the executable takes: packed, or
+        ``fn``'s (N, C, H, W) on a mesh executable."""
+        if self.entry is None:
+            return (self.batch, *self.image_shape) if self.batch > 1 \
+                else self.image_shape
+        return packed_shape(self.image_shape, self.batch)
+
+    def images(self, xin):
+        """The (C, H, W) / (N, C, H, W) images inside an ``in_shape``
+        array; a view of a numpy one."""
+        if self.entry is None:
+            return xin
+        return unpack_images(xin, self.image_shape, self.batch)
+
+    def pack(self, x) -> np.ndarray:
+        """A new ``in_shape`` array holding image(s) ``x``."""
+        xin = np.zeros(self.in_shape, np.float32)
+        view = self.images(xin)
+        if np.shape(x) != view.shape:
+            raise ValueError(f"expected input of shape {view.shape}, got "
+                             f"{np.shape(x)}")
+        view[...] = np.asarray(x)
+        return xin
 
     def __call__(self, x):
-        return self.fn(jnp.asarray(x), self.params)
+        """Run on image(s) ``x``, (C, H, W) or (N, C, H, W), or on an
+        ``in_shape`` array that already holds them packed."""
+        if self.entry is None:
+            return self.fn(jnp.asarray(x), self.params)
+        if np.shape(x) != self.in_shape:
+            x = self.pack(x)
+        return self.entry(x, self.params)
 
     def scopes(self) -> set:
         """Every ``node:``/``edge:`` scope the plan's executable opens."""
@@ -201,14 +261,18 @@ class CompiledNet:
         return out
 
     def op_scopes(self, x_shape) -> Dict[str, str]:
-        """``{HLO instruction name: scope}`` of the executable compiled
-        for input shape ``x_shape``: which PBQP node (``node:<id>``) or
-        layout conversion edge (``edge:<src>-><dst>``) each instruction
-        of the optimized module — and so each device op of a profile —
-        belongs to.  Lowers and compiles, which for a shape already run
-        is a cache hit.  Needs a jitted executable (``jit=True``)."""
-        x = jax.ShapeDtypeStruct(tuple(x_shape), jnp.float32)
-        text = self.fn.lower(x, self.params).compile().as_text()
+        """``{HLO instruction name: scope}`` of the executable that
+        :meth:`__call__` runs for images of shape ``x_shape``: which PBQP
+        node (``node:<id>``) or layout conversion edge
+        (``edge:<src>-><dst>``) each instruction of the optimized module
+        — and so each device op of a profile — belongs to.  Lowers and
+        compiles, which for a shape already run is a cache hit.  Needs a
+        jitted executable (``jit=True``)."""
+        fn, shape = self.fn, tuple(x_shape)
+        if self.entry is not None:
+            fn, shape = self.entry, packed_shape(shape[-3:], self.batch)
+        x = jax.ShapeDtypeStruct(shape, jnp.float32)
+        text = fn.lower(x, self.params).compile().as_text()
         return hlo_op_scopes(text, self.scopes())
 
 
@@ -228,6 +292,12 @@ def compile_plan(sel: SelectionResult, raw_params: Dict[str, Dict],
     is paid once, which is exactly the amortization the batch-aware
     cost model prices (``Scenario.n``).  Input becomes (N, C, H, W) and
     every output gains a leading N axis.
+
+    **Packed input.**  A single-device executable's ``entry`` takes its
+    image(s) packed (:func:`packed_shape`), in bytes that already are
+    the TPU's tiled layout, and unpacks them on the device inside the
+    same program; ``__call__`` packs.  ``fn``, the same walk on
+    unpacked input, is compiled only if called.
 
     **Transform fusion pass.**  Edges the selection realized as fused
     (``sel.fusions``, see :func:`~repro.core.selection.select_pbqp` with
@@ -262,6 +332,8 @@ def compile_plan(sel: SelectionResult, raw_params: Dict[str, Dict],
                          "batch >= 2 (a single image cannot be sharded "
                          "over the data axis)")
     net = sel.net
+    chw = tuple(next(net.nodes[nid].out_shape for nid in net.order
+                     if net.nodes[nid].kind == "input"))
     dp_nodes = tp_nodes = pp_nodes = 0
     d_mesh = 1
     batch_axes: tuple = ()
@@ -396,16 +468,20 @@ def compile_plan(sel: SelectionResult, raw_params: Dict[str, Dict],
                            fused_edges=len(fusions), mesh=mesh,
                            dp_nodes=dp_nodes, mesh_mode=mode,
                            makers=makers, tp_nodes=tp_nodes,
-                           pp_nodes=pp_nodes)
+                           pp_nodes=pp_nodes, image_shape=chw)
     else:
         run = _image_walker(sel, net, makers, barrier)
         if batch > 1:
             run = jax.vmap(run, in_axes=(0, None))
-        fn = jax.jit(run) if jit else run
-        cnet = CompiledNet(sel, fn, packed,
+
+        def run_packed(xp, params):
+            return run(unpack_images(xp, chw, batch), params)
+
+        cnet = CompiledNet(sel, jax.jit(run) if jit else run, packed,
                            build_s=time.perf_counter() - t0,
                            batch=batch, fused_edges=len(fusions),
-                           makers=makers)
+                           makers=makers, image_shape=chw,
+                           entry=jax.jit(run_packed) if jit else run_packed)
     get_tracer().emit("compile", t0, time.perf_counter(),
                       nodes=len(net.order), batch=batch,
                       fused_edges=cnet.fused_edges,

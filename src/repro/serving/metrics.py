@@ -36,6 +36,9 @@ COUNT_FIELDS = (
     # batched execution: executable invocations serving > 0 requests
     # each, and how many requests shared an invocation with another
     "batch_calls", "coalesced",
+    # input staging buffers: an executable call's input written into a
+    # kept buffer (hit) or a newly allocated one (miss)
+    "staging_hits", "staging_misses",
     # solver / compiler work actually performed
     "solves", "warm_solves", "compiles", "mesh_compiles",
     # continuous-batching scheduler (repro.serving.scheduler): requests

@@ -174,6 +174,9 @@ class PlanServer:
                                         thread_name_prefix="planserver")
         #: request-shape -> output-node expected shapes (crop targets)
         self._out_shapes = LRU(512)
+        #: free input staging buffers per executable input shape
+        #: (``CompiledNet.in_shape``), at most one per worker each
+        self._staging = LRU(lru_capacity)
         #: micro-batching admission queue: (image, future, enqueue time)
         self._queue: List[Tuple[np.ndarray, Future, float]] = []
         self._closed = False
@@ -410,10 +413,11 @@ class PlanServer:
     # -----------------------------------------------------------------
     # guarded execution + quarantine
     # -----------------------------------------------------------------
-    def _execute_guarded(self, cnet: CompiledNet, xb, bshape: Shape,
-                         nb: int
+    def _execute_guarded(self, cnet: CompiledNet, xin: np.ndarray,
+                         bshape: Shape, nb: int
                          ) -> Tuple[Dict[str, np.ndarray], CompiledNet]:
-        """Run the executable under the kernel circuit breaker.
+        """Run the executable on its input ``xin`` (:meth:`_stage`)
+        under the kernel circuit breaker.
 
         Crashes and non-finite outputs count as kernel failures: the
         culprit primitive is attributed (the injected spec's target, or
@@ -428,7 +432,7 @@ class PlanServer:
         may differ from the argument after a quarantine re-solve.
         """
         if not self.guard_outputs and self.fault_injector is None:
-            return _run(cnet, xb), cnet
+            return _run(cnet, xin), cnet
         bkey = bucket_key(bshape, nb)
         attempts = 0
         while True:
@@ -436,7 +440,7 @@ class PlanServer:
             failure: Optional[BaseException] = None
             culprit: Optional[str] = None
             try:
-                out = _run(cnet, xb)
+                out = _run(cnet, xin)
             except Exception as exc:
                 failure = exc
             if self.fault_injector is not None:
@@ -473,7 +477,7 @@ class PlanServer:
             # ---- failure path ----
             self.counters.add(kernel_failures=1)
             if culprit is None:
-                culprit = diagnose_nonfinite(cnet, xb)
+                culprit = diagnose_nonfinite(cnet, cnet.images(xin))
             tripped = culprit is not None and \
                 self._quarantine_bucket(bshape, nb, culprit)
             attempts += 1
@@ -484,7 +488,11 @@ class PlanServer:
             # the trip rotated the bucket's cache key and evicted its
             # plan + executable: this re-solves (culprit banned, warm-
             # started from the poisoned plan), recompiles, and retries
+            images = cnet.images(xin)
             cnet = self.compiled_for(bshape, n=nb)
+            if cnet.in_shape != xin.shape:
+                # the new plan joined or left the mesh
+                xin = cnet.pack(images)
 
     def _quarantine_bucket(self, bshape: Shape, nb: int,
                            primitive: str) -> bool:
@@ -572,6 +580,46 @@ class PlanServer:
     # -----------------------------------------------------------------
     # request paths
     # -----------------------------------------------------------------
+    def _stage(self, cnet: CompiledNet, imgs: Sequence[np.ndarray]
+               ) -> np.ndarray:
+        """``cnet``'s input holding ``imgs``, one a batch row, each at
+        its bucket's corner: a kept buffer if one is free, else a new
+        one.  Every value no image fills is written zero, since a kept
+        buffer holds its last call's images.  Hand it back with
+        :meth:`_unstage` once the call's outputs are on the host."""
+        with get_tracer().span("prepare") as sp:
+            with self._lock:
+                free = self._staging.get(cnet.in_shape)
+                xin = free.pop() if free else None
+            hit = xin is not None
+            if xin is None:
+                xin = np.empty(cnet.in_shape, np.float32)
+            rows = cnet.images(xin)
+            if cnet.batch == 1:
+                rows = rows[None]
+            for row, x in zip(rows, imgs):
+                c, h, w = x.shape
+                row[:c, :h, :w] = x
+                row[c:] = 0
+                row[:c, h:] = 0
+                row[:c, :h, w:] = 0
+            rows[len(imgs):] = 0
+            # a packed row's tail past C*H*W
+            xin.reshape(cnet.batch, -1)[:, rows[0].size:] = 0
+            sp.set(staged="hit" if hit else "miss")
+        self.counters.add(staging_hits=int(hit), staging_misses=int(not hit))
+        return xin
+
+    def _unstage(self, xin: np.ndarray) -> None:
+        """Keep a staging buffer whose call is done with it."""
+        with self._lock:
+            free = self._staging.get(xin.shape)
+            if free is None:
+                free = []
+                self._staging.put(xin.shape, free)
+            if len(free) < self.worker_target:
+                free.append(xin)
+
     def infer(self, x_chw: np.ndarray) -> Dict[str, np.ndarray]:
         """Execute one request: bucket, pad, run, crop, return outputs."""
         x = np.asarray(x_chw, np.float32)
@@ -582,22 +630,16 @@ class PlanServer:
             cnet = self.compiled_for(x.shape)
             bshape = bucket_shape(x.shape, self.policy)
             bkey = bucket_key(bshape, cnet.batch)
-            with tracer.span("prepare"):
-                pads = [(0, b - s) for b, s in zip(bshape, x.shape)]
-                xb = np.pad(x, pads)
-                if cnet.batch > 1:
-                    # a policy whose batch bucket for n=1 is > 1 (linear
-                    # batch mode, min_n > 1) hands the single request a
-                    # batched executable: embed the image as row 0, zero
-                    # rows pad
-                    xb = np.concatenate(
-                        [xb[None], np.zeros((cnet.batch - 1, *bshape),
-                                            np.float32)])
+            # a policy whose batch bucket for n=1 is > 1 (linear batch
+            # mode, min_n > 1) hands the single request a batched
+            # executable: the image is row 0, zero rows pad
+            xin = self._stage(cnet, [x])
             expected = self._expected_out_shapes(x.shape)
             t0 = time.perf_counter()
             with tracer.span("execute", bucket=bkey):
-                out, cnet = self._execute_guarded(cnet, xb, bshape,
+                out, cnet = self._execute_guarded(cnet, xin, bshape,
                                                   cnet.batch)
+            self._unstage(xin)
             with tracer.span("crop"):
                 out = {nid: self._crop(
                            v[0] if cnet.batch > 1 else v,
@@ -612,9 +654,9 @@ class PlanServer:
         """Execute a batch of requests, one executable call per bucket.
 
         Requests group by spatial bucket; each group (chunked at
-        ``policy.max_n``) is stacked into a zero-padded (N', C', H', W')
-        tensor — N' the group's pow2 batch bucket — and runs through the
-        batched executable in ONE invocation.  Per-request outputs are
+        ``policy.max_n``) is written, zero-padded to (N', C', H', W') —
+        N' the group's pow2 batch bucket — into the batched executable's
+        input (:meth:`_stage`) and runs through it in ONE invocation.  Per-request outputs are
         sliced off the batch axis and cropped exactly like
         :meth:`infer`, so ``infer_batch(xs)[i] == infer(xs[i])`` up to
         float reassociation.  Returns one output dict per request, in
@@ -660,17 +702,13 @@ class PlanServer:
                 seen_specs.add((bshape, nb))
             else:
                 cnet = self.compiled_for(bshape, n=nb)
-            with tracer.span("prepare"):
-                xb = np.zeros((nb, *bshape), np.float32)
-                for row, i in enumerate(chunk):
-                    x = imgs[i]
-                    xb[row, :x.shape[0], :x.shape[1], :x.shape[2]] = x
+            xin = self._stage(cnet, [imgs[i] for i in chunk])
             bkey = bucket_key(bshape, nb)
             t0 = time.perf_counter()
             with tracer.span("execute", bucket=bkey,
                              coalesced=len(chunk)):
-                out, cnet = self._execute_guarded(
-                    cnet, xb if nb > 1 else xb[0], bshape, nb)
+                out, cnet = self._execute_guarded(cnet, xin, bshape, nb)
+            self._unstage(xin)
             # coalesced counts per *invocation*: requests that
             # shared this executable call with at least one other
             self.counters.add(_bucket=bkey, batch_calls=1,
@@ -782,12 +820,12 @@ class PlanServer:
         self._pool.shutdown(wait=True)
 
 
-def _run(cnet: CompiledNet, xb: np.ndarray) -> Dict[str, np.ndarray]:
+def _run(cnet: CompiledNet, xin: np.ndarray) -> Dict[str, np.ndarray]:
     """Call the executable, then copy its outputs to the host: ``dispatch``
     returns once the call is queued, ``fetch`` waits for the device."""
     tracer = get_tracer()
     with tracer.span("dispatch"):
-        outs = cnet(xb)
+        outs = cnet(xin)
     with tracer.span("fetch"):
         return {nid: np.asarray(v) for nid, v in outs.items()}
 

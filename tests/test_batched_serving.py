@@ -230,6 +230,189 @@ class TestInferBatch:
         srv.close()
 
 
+class TestStagedInput:
+    """Each call's input is written once, packed (``packed_shape``), into
+    a staging buffer the server keeps for the next call."""
+
+    @pytest.mark.parametrize("chw", [(3, 32, 32), (3, 30, 30)])
+    @pytest.mark.parametrize("batch", [1, 4])
+    def test_packed_entry_is_the_walker_bit_for_bit(self, chw, batch):
+        """3x32x32 fills its packed rows; 3x30x30 leaves a zero tail."""
+        from repro.core.plan import packed_shape, unpack_images
+        net = conv_stack(chw, depth=2, width=8).with_batch(batch)
+        cnet = compile_plan(select_pbqp(net, CM), net.init_params(0),
+                            batch=batch)
+        assert cnet.in_shape == packed_shape(chw, batch) == \
+            ((24, 128) if batch == 1 else (batch, 24, 128))
+        x = np.random.default_rng(5).normal(
+            size=chw if batch == 1 else (batch, *chw)).astype(np.float32)
+        xin = cnet.pack(x)
+        view = unpack_images(xin, chw, batch)
+        assert np.shares_memory(view, xin)
+        np.testing.assert_array_equal(view, x)
+        assert not xin.reshape(batch, -1)[:, int(np.prod(chw)):].any()
+        want = cnet.fn(x, cnet.params)
+        for got in (cnet(x), cnet(xin)):
+            assert set(got) == set(want)
+            for k in want:
+                np.testing.assert_array_equal(np.asarray(got[k]),
+                                              np.asarray(want[k]))
+
+    @staticmethod
+    def _inputs(monkeypatch):
+        """Every input the executables are called with, copied."""
+        from repro.core.plan import CompiledNet
+        seen = []
+        sound = CompiledNet.__call__
+
+        def spy(self, x):
+            seen.append((self, np.array(x)))
+            return sound(self, x)
+        monkeypatch.setattr(CompiledNet, "__call__", spy)
+        return seen
+
+    @staticmethod
+    def _assert_same(got, want):
+        for g, w in zip(got, want, strict=True):
+            assert set(g) == set(w)
+            for k in w:
+                np.testing.assert_array_equal(g[k], w[k])
+
+    def test_partial_batch_after_a_full_one_reads_zero_rows(
+            self, monkeypatch):
+        builder = lambda s: conv_stack(s, depth=2, width=8)
+        rng = np.random.default_rng(6)
+        full = [rng.normal(size=(4, 16, 16)).astype(np.float32)
+                for _ in range(4)]
+        part = [rng.normal(size=(4, 16, 16)).astype(np.float32)
+                for _ in range(3)]
+        srv = _server(builder)
+        fresh = _server(builder)
+        seen = self._inputs(monkeypatch)
+        try:
+            srv.infer_batch(full)
+            got = srv.infer_batch(part)       # batch bucket 4, one row free
+            assert srv.stats()["staging_hits"] == 1
+            cnet, xin = seen[-1]
+            rows = cnet.images(xin)
+            np.testing.assert_array_equal(rows[:3], np.stack(part))
+            assert not rows[3].any()
+            assert not xin.reshape(4, -1)[:, rows[0].size:].any()
+            self._assert_same(got, fresh.infer_batch(part))
+        finally:
+            srv.close()
+            fresh.close()
+
+    def test_smaller_image_in_a_pow2_bucket_reads_zero_padding(
+            self, monkeypatch):
+        builder = lambda s: conv_stack(s, depth=2, width=8)
+        rng = np.random.default_rng(7)
+        big = rng.normal(size=(3, 30, 30)).astype(np.float32)
+        small = rng.normal(size=(3, 20, 17)).astype(np.float32)
+        srv = _server(builder)
+        fresh = _server(builder)
+        seen = self._inputs(monkeypatch)
+        try:
+            assert srv.policy.bucket(big.shape) == \
+                srv.policy.bucket(small.shape) == (4, 32, 32)
+            srv.infer(big)
+            got = srv.infer(small)
+            assert srv.stats()["staging_hits"] == 1
+            cnet, xin = seen[-1]
+            img = cnet.images(xin)
+            np.testing.assert_array_equal(img[:3, :20, :17], small)
+            img[:3, :20, :17] = 0
+            assert not xin.any()
+            self._assert_same([got], [fresh.infer(small)])
+        finally:
+            srv.close()
+            fresh.close()
+
+    def test_a_new_buffer_is_written_whole(self, monkeypatch):
+        """A new buffer holds whatever the allocator left: every value
+        no image fills is written zero, the tail past C*H*W of each
+        packed row (3x30x30 fills 2700 of 3072) included."""
+        real = np.empty
+
+        def dirty(shape, dtype=float, *a, **k):
+            out = real(shape, dtype, *a, **k)
+            if out.dtype == np.float32:
+                out.fill(np.nan)
+            return out
+        monkeypatch.setattr(np, "empty", dirty)
+        seen = self._inputs(monkeypatch)
+        srv = _server(lambda s: conv_stack(s, depth=1, width=8),
+                      policy=BucketPolicy(spatial="exact", channel="exact",
+                                          min_hw=8, max_hw=64))
+        xs = [np.full((3, 30, 30), i + 1.0, np.float32) for i in range(3)]
+        try:
+            srv.infer_batch(xs)               # batch bucket 4
+        finally:
+            srv.close()
+        cnet, xin = seen[-1]
+        assert xin.shape == (4, 24, 128)
+        rows = cnet.images(xin)
+        np.testing.assert_array_equal(rows[:3], np.stack(xs))
+        rows[:3] = 0
+        assert not xin.any()
+
+    @pytest.mark.parametrize("threads", [2, 12])
+    def test_threads_share_a_bucket(self, threads):
+        """Concurrent callers of one bucket get one thread's answers: no
+        buffer is written by one call while another reads it.  A server
+        whose pool has a worker per caller allocates at most one buffer
+        per caller."""
+        import sys
+        import threading
+        rng = np.random.default_rng(8)
+        waves = [[rng.normal(size=(3, 16, 16)).astype(np.float32)
+                  for _ in range(4)] for _ in range(4 * threads)]
+        one = _server()
+        srv = _server(max_workers=threads)
+        switch = sys.getswitchinterval()
+        try:
+            want = [one.infer_batch(w) for w in waves]
+            srv.infer_batch(waves[0])         # solve + compile
+            got = [None] * len(waves)
+
+            def client(k):
+                for i in range(k, len(waves), threads):
+                    got[i] = srv.infer_batch(waves[i])
+            ts = [threading.Thread(target=client, args=(k,))
+                  for k in range(threads)]
+            sys.setswitchinterval(1e-5)
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in ts)
+        finally:
+            sys.setswitchinterval(switch)
+            one.close()
+            srv.close()
+        for g, w in zip(got, want, strict=True):
+            self._assert_same(g, w)
+        s = srv.stats()
+        assert s["staging_misses"] <= threads
+        assert s["staging_hits"] + s["staging_misses"] == len(waves) + 1
+
+    def test_staging_counters(self):
+        srv = _server()
+        x = np.zeros((3, 16, 16), np.float32)
+        try:
+            for _ in range(3):
+                srv.infer_batch([x] * 4)
+            for _ in range(2):
+                srv.infer(x)
+            s = srv.stats()
+            # one buffer per executable input shape, reused after
+            assert (s["staging_misses"], s["staging_hits"]) == (2, 3)
+            text = srv.metrics_text()
+        finally:
+            srv.close()
+        assert "staging_hits" in text and "staging_misses" in text
+
+
 class TestOutputCropping:
     def test_infer_crops_to_request_extent(self):
         """Bucketed output slices match an exact run on the unpadded

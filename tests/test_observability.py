@@ -401,6 +401,22 @@ class TestServingSpans:
                         and r["parent"] == execute["span"]]
                 assert r["dur_s"] >= 0.0
 
+    def test_prepare_says_whether_its_buffer_was_kept(self, sink):
+        """``prepare`` carries ``staged``: a new buffer on a bucket's
+        first call, the kept one after."""
+        srv = _server()
+        x = np.zeros((3, 12, 12), np.float32)
+        try:
+            for _ in range(2):
+                srv.infer(x)
+                srv.infer_batch([x] * 2)
+            s = srv.stats()
+        finally:
+            srv.close()
+        assert [r["staged"] for r in _by_name(sink, "prepare")] == \
+            ["miss", "miss", "hit", "hit"]
+        assert (s["staging_misses"], s["staging_hits"]) == (2, 2)
+
     def test_spans_reach_a_profiler_capture(self, tmp_path):
         """With the JSONL sink off, a ``jax.profiler`` capture holds the
         request's spans on its host planes."""
@@ -595,6 +611,38 @@ class TestOpScopes:
         monkeypatch.setattr(jax, "named_scope",
                             lambda name: contextlib.nullcontext())
         assert program(compile_plan(sel, params)) == scoped
+
+    @pytest.mark.parametrize("batch", [1, 4])
+    def test_op_scopes_name_the_packed_executable(self, batch):
+        """The map is of the executable ``__call__`` runs, which takes
+        its input packed: mapping it compiles nothing more, every key is
+        one of its instructions, and the packed parameter and its unpack
+        go to the scope of what they feed."""
+        import re
+
+        import jax
+        sel, params = _alternating_plan(ops=True)
+        s = sel if batch == 1 else select_pbqp(
+            sel.net.with_batch(batch), CM)
+        cnet = compile_plan(s, s.net.init_params(0), batch=batch)
+        shape = (8, 12, 12) if batch == 1 else (batch, 8, 12, 12)
+        cnet(np.zeros(shape, np.float32))
+        before = plan_mod.xla_compile_stats()["xla_compiles"]
+        got = cnet.op_scopes(shape)
+        assert plan_mod.xla_compile_stats()["xla_compiles"] == before
+        text = cnet.entry.lower(
+            jax.ShapeDtypeStruct(cnet.in_shape, np.float32),
+            cnet.params).compile().as_text()
+        entry = text[text.index("\nENTRY"):]
+        dims = ",".join(map(str, cnet.in_shape))
+        (param,) = re.findall(
+            rf"%(\S+) = f32\[{dims}\]\S* parameter\(0\)", entry)
+        (feed,) = re.findall(
+            rf"%(\S+) = [^\n]*\(%{re.escape(param)}[,)]", entry)[:1]
+        assert got and all(f"%{k} = " in text for k in got)
+        assert set(got.values()) <= cnet.scopes()
+        assert got[param] == got[feed]
+        assert got[param] in {"node:conv0", "edge:data->conv0"}
 
     def test_hlo_scopes_from_bodies_and_consumers(self):
         """A fusion without a scope takes its body's; an unscoped copy

@@ -258,3 +258,32 @@ def test_mesh_plan_with_pallas_kernels(topo, chip_wrappers, monkeypatch,
                              sharding=NamedSharding(mesh, P()))
     compiled = cnet.fn.lower(x, params).compile()
     assert _kernel_calls(compiled) >= 1
+
+
+def test_packed_batch_entry_is_tiled_as_row_major(one_chip, chip_wrappers):
+    """GoogLeNet's batch-8 executable, on the plan solved at the chip's
+    prices, takes one image parameter: packed f32[8,1176,128], whose
+    tiled layout is row-major, so the host copies it without a
+    relinearize (3x224x224 = 1176 x 128, 1176 = 147 x 8)."""
+    import re
+
+    from repro.convnets import NETWORKS
+    from repro.core.costs import TPU_V5E_SPEC, AnalyticCostModel
+    from repro.core.plan import compile_plan
+    from repro.core.selection import select_pbqp
+    net = NETWORKS["googlenet"](1.0).with_batch(8)
+    sel = select_pbqp(net, AnalyticCostModel(TPU_V5E_SPEC,
+                                             include_tpu_only=True))
+    cnet = compile_plan(sel, net.init_params(0), batch=8)
+    assert cnet.in_shape == (8, 1176, 128)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    text = cnet.entry.lower(
+        jax.ShapeDtypeStruct(cnet.in_shape, jnp.float32, sharding=one_chip),
+        jax.tree.map(on_chip, cnet.params)).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    shapes = {int(i): shape for shape, i in
+              re.findall(r"= (\S+) parameter\((\d+)\)", entry)}
+    assert shapes.pop(0) == "f32[8,1176,128]{2,1,0:T(8,128)}"
+    assert not [p for p in shapes.values() if p.startswith("f32[8,")]
